@@ -3,7 +3,7 @@
 This is the paper's actual loop (eq. 2 — the agent learns from measured
 execution time, not a cost model): every reward below comes from
 compiling and timing the Pallas kernels via ``oracle="measured"``.  On
-TPU/GPU the kernels compile natively; on CPU they run in Pallas interpret
+a TPU the kernels compile natively; on CPU they run in Pallas interpret
 mode with capped shapes, so this exact script is the CI smoke for the
 whole measure→reward→train→deploy chain.
 

@@ -174,8 +174,8 @@ class NeuroVectorizer:
             constructed :class:`Agent`.  Extra ``agent_kwargs`` flow to
             ``make_agent`` (e.g. ``lr=``, ``mode=``, ``embed_fn=``).
     oracle: a row of the matrix above.  ``"measured"`` assembles
-            :func:`repro.measure.make_measured_env` — real hardware on
-            TPU/GPU, interpret-mode Pallas on CPU.
+            :func:`repro.measure.make_measured_env` — compiled kernels
+            timed on the TPU, interpret-mode Pallas elsewhere.
     transport: a column of the matrix above (``oracle="measured"`` only).
     workers: pool size for ``transport="pool"``.
     hosts:  ``serve-worker`` addresses (``["host:port", ...]``) for

@@ -26,29 +26,40 @@ def _chunk_kernel(x_ref, b_ref, c_ref, la_ref, o_ref, state_ref, *, Q: int):
     x = x_ref[0].astype(jnp.float32)             # (Q, P)
     Bm = b_ref[0].astype(jnp.float32)            # (Q, N)
     Cm = c_ref[0].astype(jnp.float32)            # (Q, N)
-    la = la_ref[0].astype(jnp.float32)           # (Q,) via (1, Q) block
-    cum = jnp.cumsum(la)                         # inclusive (Q,)
+    la = la_ref[0].astype(jnp.float32)           # (Q, 1)
 
-    # intra-chunk
-    li = cum[:, None] - cum[None, :]             # decay j..i
+    # Mosaic has no cumsum: the inclusive prefix sum is a product with the
+    # lower-triangular ones matrix, taken once as a column and once as a
+    # row so the pairwise decays need no transpose
     causal = (jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
               >= jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1))
-    L = jnp.where(causal, jnp.exp(li), 0.0)
+    tril = causal.astype(jnp.float32)
+    hi = jax.lax.Precision.HIGHEST
+    cum = jax.lax.dot_general(tril, la, (((1,), (0,)), ((), ())),
+                              precision=hi,
+                              preferred_element_type=jnp.float32)   # (Q, 1)
+    cum_row = jax.lax.dot_general(la, tril, (((0,), (1,)), ((), ())),
+                                  precision=hi,
+                                  preferred_element_type=jnp.float32)  # (1, Q)
+    total = jnp.sum(la, axis=0, keepdims=True)   # (1, 1)
+
+    # intra-chunk
+    L = jnp.where(causal, jnp.exp(cum - cum_row), 0.0)   # decay j..i
     cb = jax.lax.dot_general(Cm, Bm, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
     y = jax.lax.dot_general(cb * L, x, (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32)
 
     # inter-chunk from carried state (P, N)
-    y += jnp.exp(cum)[:, None] * jax.lax.dot_general(
+    y += jnp.exp(cum) * jax.lax.dot_general(
         Cm, state_ref[...], (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)
 
     # state update
-    seg = jnp.exp(cum[-1] - cum)                 # (Q,)
-    state_ref[...] = (state_ref[...] * jnp.exp(cum[-1])
+    seg = jnp.exp(total - cum)                   # (Q, 1)
+    state_ref[...] = (state_ref[...] * jnp.exp(total)
                       + jax.lax.dot_general(
-                          x, Bm * seg[:, None], (((0,), (0,)), ((), ())),
+                          x, Bm * seg, (((0,), (0,)), ((), ())),
                           preferred_element_type=jnp.float32))
     o_ref[0] = y.astype(o_ref.dtype)
 
@@ -56,7 +67,10 @@ def _chunk_kernel(x_ref, b_ref, c_ref, la_ref, o_ref, state_ref, *, Q: int):
 def chunk_scan_pallas(x: jax.Array, Bm: jax.Array, Cm: jax.Array,
                       la: jax.Array, *, chunk: int,
                       interpret: bool = False) -> jax.Array:
-    """x: (G, S, P); Bm/Cm: (G, S, N); la: (G, S) log-decay.  -> y (G, S, P)."""
+    """x: (G, S, P); Bm/Cm: (G, S, N); la: (G, S) log-decay.  -> y (G, S, P).
+
+    ``la`` travels as ``(G, S, 1)`` blocked ``(1, Q, 1)``: a ``(1, Q)`` block
+    of ``(G, S)`` breaks the TPU's (8, 128) block rule for any Q < S."""
     G, S, P = x.shape
     N = Bm.shape[-1]
     Q = min(chunk, S)
@@ -69,10 +83,10 @@ def chunk_scan_pallas(x: jax.Array, Bm: jax.Array, Cm: jax.Array,
             pl.BlockSpec((1, Q, P), lambda g, c: (g, c, 0)),
             pl.BlockSpec((1, Q, N), lambda g, c: (g, c, 0)),
             pl.BlockSpec((1, Q, N), lambda g, c: (g, c, 0)),
-            pl.BlockSpec((1, Q), lambda g, c: (g, c)),
+            pl.BlockSpec((1, Q, 1), lambda g, c: (g, c, 0)),
         ],
         out_specs=pl.BlockSpec((1, Q, P), lambda g, c: (g, c, 0)),
         out_shape=jax.ShapeDtypeStruct((G, S, P), x.dtype),
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
         interpret=interpret,
-    )(x, Bm, Cm, la)
+    )(x, Bm, Cm, la[..., None])

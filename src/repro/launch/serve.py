@@ -9,17 +9,24 @@ Smoke scale on CPU::
 Tile tuning: ``--autotune brute`` plans tiles for the serving kernels with
 any registered agent (modelled speedup is printed); ``--tiles f.json``
 loads a saved :class:`~repro.api.TileProgram` instead; ``--inject`` routes
-the decode through the tuned Pallas kernels (interpret mode off-TPU).
-``--measured`` swaps the analytic reward oracle for compile-and-time
-measurement of the kernels themselves (``repro.measure``; native on
-TPU/GPU, interpret-mode with capped shapes on CPU) and ``--measure-db
-PATH`` persists the timings so repeat invocations re-time nothing.
-``--transport pool --workers N`` fans the measurements out to N
-subprocess workers (the ``WorkerPoolTransport``) instead of timing in
-this process; ``--transport socket --hosts a:7761,b:7761`` ships them to
-remote ``python -m repro.fleet serve-worker`` daemons instead
+prefill and decode through the tuned Pallas kernels (compiled on TPU,
+interpret mode elsewhere).  ``--measured`` swaps the analytic reward
+oracle for compile-and-time measurement of the kernels themselves
+(``repro.measure``; compiled on TPU, interpret-mode with capped shapes on
+CPU) and ``--measure-db PATH`` persists the timings so repeat invocations
+re-time nothing; a measured tune that ends on the analytic fallback
+(``health() != "ok"``) exits non-zero.  ``--transport pool --workers N``
+fans the measurements out to N subprocess workers (the
+``WorkerPoolTransport``, CPU only: a TPU belongs to one process) instead
+of timing in this process; ``--transport socket --hosts a:7761,b:7761``
+ships them to remote ``python -m repro.fleet serve-worker`` daemons instead
 (``repro.fleet``; a ``fleet://host:port`` ``--measure-db`` attaches the
 shared artifact service).
+
+Full width on one TPU v5e chip (``chip_smoke.py`` drives this)::
+
+  python -m repro.launch.serve --arch stablelm_3b --full --batch 4 \
+      --prompt-len 128 --gen 16 --autotune brute --inject
 
 Warm starts (``repro.artifacts``): ``--agent-ckpt DIR`` restores a
 fitted agent saved by ``nv.save()``/``save_agent`` and skips the fit
@@ -39,8 +46,45 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
+from repro.measure.runner import default_interpret
 from repro.models.lm import build_model
 from repro.train.steps import make_prefill_step, make_serve_step
+
+
+def init_params(model, seed: int = 0):
+    """Seeded parameters built in one device program: an eager init keeps
+    every per-layer tree alive beside the stacked copy, about twice the
+    block weights at once."""
+    return jax.jit(model.init)(jax.random.PRNGKey(seed))
+
+
+def make_requests(cfg, batch: int, prompt_len: int):
+    """The seeded prompt batch (plus stub frontend inputs) ``main`` serves."""
+    prompts = jax.random.randint(jax.random.PRNGKey(1), (batch, prompt_len),
+                                 0, cfg.vocab_size, jnp.int32)
+    out = {"tokens": prompts}
+    if cfg.frontend == "vision":
+        out["frontend_embeds"] = jnp.zeros(
+            (batch, cfg.n_frontend_tokens, cfg.d_model))
+    if cfg.enc_dec:
+        out["src_embeds"] = jax.random.normal(
+            jax.random.PRNGKey(2), (batch, prompt_len, cfg.d_model)) * 0.02
+    return out
+
+
+def serving_sites(model, params, batch, cache) -> list:
+    """The kernel sites of one prefill and one decode step, deduplicated
+    (prefill and decode share the weight-shaped sites' names)."""
+    from repro import api
+
+    B = batch["tokens"].shape[0]
+    tok = jax.ShapeDtypeStruct((B, 1), jnp.int32)
+    sites = {s.key(): s for s in api.extract_sites(
+        make_prefill_step(model), params, batch, cache)}
+    sites.update((s.key(), s) for s in api.extract_sites(
+        make_serve_step(model), params, tok, jnp.int32(0), cache))
+    return list(sites.values())
 
 
 def _warn_missing_tiles(prog, sites) -> list:
@@ -93,7 +137,18 @@ def _serving_plan(args, sites):
               f"{st['serving_shed_total']}, fused dispatches: "
               f"{st['serving_fused_dispatches_total']}, "
               f"health: {svc.server.health()}")
+        if args.measured:
+            _require_measured(sess.health())
     return prog
+
+
+def _require_measured(health: str) -> None:
+    """A measured tune that fell back to the analytic model did not
+    measure: fail the run instead of serving a plan priced by the model."""
+    if health != "ok":
+        raise SystemExit(f"[serve] measured tuning ended with health "
+                         f"{health!r}: the plan was priced by the analytic "
+                         f"fallback, not by measurement")
 
 
 def _tile_plan(args, model, params, batch, cache):
@@ -101,14 +156,9 @@ def _tile_plan(args, model, params, batch, cache):
     through the ``repro.api`` facade (or load one from disk)."""
     from repro import api
 
-    B = batch["tokens"].shape[0]
-    tok = jax.ShapeDtypeStruct((B, 1), jnp.int32)
-    sites = {s.key(): s for s in api.extract_sites(
-        make_prefill_step(model), params, batch, cache)}
-    sites.update((s.key(), s) for s in api.extract_sites(
-        make_serve_step(model), params, tok, jnp.int32(0), cache))
-    sites = list(sites.values())
-
+    sites = serving_sites(model, params, batch, cache)
+    print(f"[serve] extracted {len(sites)} kernel sites "
+          f"(prefill + decode)")
     if args.tiles:
         prog = api.TileProgram.load(args.tiles)
         _warn_missing_tiles(prog, sites)
@@ -173,13 +223,16 @@ def _tile_plan(args, model, params, batch, cache):
                 "inactive (DB too cold to train the surrogate)"
             print(f"[serve] pruning top-{args.prune_topk}: {state}, "
                   f"{env.pruned_pairs} pairs surrogate-priced")
-        print(f"[serve] health: {nv.health()}")
+        health = nv.health()
+        print(f"[serve] health: {health}")
     if nv is not None:
         nv.close()                      # release pool workers / DB handles
         if args.trace_out:
             print(f"[serve] trace: {nv.tracer.n_spans} spans + "
                   f"{nv.tracer.n_events} events -> {args.trace_out} "
                   f"(chrome://tracing via repro.obs.to_chrome_trace)")
+        if args.measured:
+            _require_measured(health)
     return prog
 
 
@@ -240,7 +293,8 @@ def main(argv=None):
                          "site sets are answered by lookup (zero agent "
                          "inferences)")
     ap.add_argument("--inject", action="store_true",
-                    help="run decode through the tuned Pallas kernels")
+                    help="run prefill and decode through the tuned Pallas "
+                         "kernels")
     ap.add_argument("--trace-out", default=None,
                     help="append the tuning span tree (session -> fit -> "
                          "tune -> submit/drain) to this JSONL trace file "
@@ -308,23 +362,16 @@ def main(argv=None):
         print(f"[serve] metrics: http://127.0.0.1:{metrics_srv.port}"
               f"/metrics (Prometheus text format)")
 
+    print(f"[serve] compile cache: {enable_compile_cache()}")
     cfg = get_config(args.arch)
     if not args.full:
         cfg = cfg.reduced()
     model = build_model(cfg)
-    params = model.init(jax.random.PRNGKey(0))
+    params = init_params(model)
 
     B = args.batch
     ctx = args.prompt_len + args.gen
-    prompts = jax.random.randint(jax.random.PRNGKey(1), (B, args.prompt_len),
-                                 0, cfg.vocab_size, jnp.int32)
-    batch = {"tokens": prompts}
-    if cfg.frontend == "vision":
-        batch["frontend_embeds"] = jnp.zeros(
-            (B, cfg.n_frontend_tokens, cfg.d_model))
-    if cfg.enc_dec:
-        batch["src_embeds"] = jax.random.normal(
-            jax.random.PRNGKey(2), (B, args.prompt_len, cfg.d_model)) * 0.02
+    batch = make_requests(cfg, B, args.prompt_len)
 
     cache = model.make_cache(B, ctx, jnp.dtype(cfg.dtype))
     prefill = jax.jit(make_prefill_step(model))
@@ -337,25 +384,41 @@ def main(argv=None):
     run_ctx = contextlib.nullcontext()
     if prog is not None and args.inject:
         from repro import api
-        # interpret keyed on the real backend: Pallas compiles natively on
-        # TPU, interprets elsewhere — independent of the model-size flag
-        run_ctx = api.inject(prog,
-                             interpret=jax.default_backend() != "tpu")
+        interpret = default_interpret()
+        run_ctx = api.inject(prog, interpret=interpret)
+        print(f"[serve] injected {len(prog.tiles)} tiles "
+              f"({'interpret' if interpret else 'compiled'} Pallas)")
 
+    # host-clock spans, each closed by block_until_ready; the first call
+    # of each step includes its compilation.  Not device metrics.
+    n_pre = cfg.n_frontend_tokens if cfg.frontend == "vision" else 0
     with run_ctx:
-        t0 = time.time()
+        t0 = time.perf_counter()
         logits, cache = prefill(params, batch, cache)
         tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)[:, None]
+        tok.block_until_ready()
+        t_prefill = time.perf_counter() - t0
         out = [tok]
-        n_pre = cfg.n_frontend_tokens if cfg.frontend == "vision" else 0
+        t_first = t_steady = 0.0
         for i in range(args.gen - 1):
+            t0 = time.perf_counter()
             pos = jnp.int32(n_pre + args.prompt_len + i)
             tok, logits, cache = serve(params, tok, pos, cache)
             out.append(tok)
-        seq = jnp.concatenate(out, axis=1)
-        dt = time.time() - t0
-    print(f"[serve] {B} requests, {args.gen} tokens each in {dt:.2f}s "
-          f"({B * args.gen / dt:.1f} tok/s)")
+            if i == 0:
+                tok.block_until_ready()
+                t_first = time.perf_counter() - t0
+                t_steady0 = time.perf_counter()
+        seq = jnp.concatenate(out, axis=1).block_until_ready()
+        if args.gen > 2:
+            t_steady = time.perf_counter() - t_steady0
+    print(f"[serve] first calls (compile + run, host clock): prefill "
+          f"{t_prefill:.3f}s, first decode step {t_first:.3f}s")
+    n_steady = max(args.gen - 2, 0)
+    if n_steady:
+        print(f"[serve] steady decode (host clock, not a device metric): "
+              f"{n_steady} steps x {B} requests in {t_steady:.3f}s "
+              f"({B * n_steady / t_steady:.1f} tok/s)")
     print("[serve] sample:", seq[0].tolist())
     if args.metrics_out:
         import json as _json

@@ -7,30 +7,48 @@ Runs end-to-end on this CPU container at reduced scale::
   PYTHONPATH=src python -m repro.launch.train --arch qwen3_8b --steps 50 \
       --ckpt-dir /tmp/ckpt
 
-On a TPU slice the same driver runs the full config over the production
-mesh (--full --model-parallel 16); jax.distributed initialization and the
-per-host data sharding come from the environment.
+On a TPU host the same driver runs the full config over a
+(data, model) mesh of the local chips, e.g. StableLM-3B on four v5e
+chips (``chip_smoke.py --four-chips`` drives this)::
+
+  python -m repro.launch.train --arch stablelm_3b --full \
+      --model-parallel 2 --steps 5 --batch 8 --seq 512
 """
 from __future__ import annotations
 
 import argparse
-import time
+import functools
 
 import jax
-import jax.numpy as jnp
-import numpy as np
 
 from repro.checkpoint.checkpoint import CheckpointManager
-from repro.configs import SHAPES, get_config
+from repro.configs import get_config
 from repro.configs.base import ShapeConfig
 from repro.data.pipeline import DataConfig, SyntheticPipeline
 from repro.distributed import sharding as shd
 from repro.ft.monitor import PreemptionHandler, StepMonitor
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh
-from repro.models import compute
+from repro.measure.runner import default_interpret
 from repro.models.lm import build_model
 from repro.optim.adamw import AdamWConfig
 from repro.train.steps import make_train_state, make_train_step
+
+
+def init_state(model, opt_cfg: AdamWConfig, mesh, mgr=None):
+    """The train state, sharded over ``mesh`` from the start: restored from
+    ``mgr``'s newest checkpoint and placed shard by shard, or created in
+    one jitted program with ``out_shardings``.  No device ever holds the
+    whole state.  Returns ``(state, shardings, start_step)``."""
+    make = functools.partial(make_train_state, model, opt_cfg=opt_cfg)
+    key = jax.random.PRNGKey(0)
+    abstract = jax.eval_shape(make, key)
+    sh = shd.named(mesh, shd.param_specs(abstract, mesh))
+    if mgr is not None:
+        restored, step = mgr.restore(abstract)
+        if step is not None:
+            return jax.device_put(restored, sh), sh, step
+    return jax.jit(make, out_shardings=sh)(key), sh, 0
 
 
 def main(argv=None):
@@ -50,6 +68,7 @@ def main(argv=None):
                     help="TileProgram json from repro.core.vectorizer; "
                          "routes hot ops through tuned Pallas kernels")
     args = ap.parse_args(argv)
+    print(f"[train] compile cache: {enable_compile_cache()}")
 
     cfg = get_config(args.arch)
     if not args.full:
@@ -63,17 +82,10 @@ def main(argv=None):
     pipe = SyntheticPipeline(cfg, shape, DataConfig(seed=0))
     step_fn = make_train_step(model, opt_cfg, accum=args.accum)
 
-    state = make_train_state(model, jax.random.PRNGKey(0), opt_cfg)
-    start_step = 0
-    mgr = None
-    if args.ckpt_dir:
-        mgr = CheckpointManager(args.ckpt_dir)
-        state, restored = mgr.restore(state)
-        if restored is not None:
-            start_step = restored
-            print(f"[train] resumed from step {restored}")
-
-    state_sh = shd.named(mesh, shd.param_specs(state, mesh))
+    mgr = CheckpointManager(args.ckpt_dir) if args.ckpt_dir else None
+    state, state_sh, start_step = init_state(model, opt_cfg, mesh, mgr)
+    if start_step:
+        print(f"[train] resumed from step {start_step}")
     jitted = jax.jit(step_fn, in_shardings=(state_sh, None),
                      out_shardings=(state_sh, None), donate_argnums=0)
 
@@ -81,8 +93,7 @@ def main(argv=None):
     if args.tune:
         from repro.core.vectorizer import TileProgram, inject
         prog = TileProgram.load(args.tune)
-        # interpret=True on CPU; on a TPU slice the kernels compile natively
-        tune_ctx = inject(prog, interpret=jax.devices()[0].platform == "cpu")
+        tune_ctx = inject(prog, interpret=default_interpret())
         tune_ctx.__enter__()        # active during tracing below
         print(f"[tune] injected {len(prog.tiles)} kernel-site tile choices")
 

@@ -7,7 +7,7 @@ stand-in.  Layered bottom-up:
 * :mod:`repro.measure.timing` — the one median-of-reps timing loop every
   consumer shares (runner + benchmarks).
 * :mod:`repro.measure.runner` — :class:`MeasureRunner`, the batched
-  compile-and-time primitive (real kernels on TPU/GPU, interpret-mode
+  compile-and-time primitive (compiled kernels on TPU, interpret-mode
   Pallas on CPU so CI runs the full loop; per-tile failures fail closed).
 * :mod:`repro.measure.db` — :class:`MeasureDB`, the persistent JSONL
   timing store (repeat autotune runs re-time nothing).

@@ -110,6 +110,9 @@ class _Job:
 class WorkerPoolTransport:
     """Subprocess measurement pool behind the MeasureTransport contract.
 
+    CPU only: on a TPU backend the constructor raises, since a chip
+    belongs to one process and in-process timing is the one chip path.
+
     Parameters
     ----------
     workers:        pool size (one subprocess + dispatcher thread each).
@@ -145,6 +148,14 @@ class WorkerPoolTransport:
                  backoff_seed: int = 0):
         if workers < 1:
             raise ValueError(f"workers must be >= 1, got {workers}")
+        import jax
+        if jax.default_backend() == "tpu":
+            # this process already holds the chip: a worker would fail or
+            # hang on it, or fall back to timing something else
+            raise RuntimeError(
+                "WorkerPoolTransport cannot time on a TPU: the chip belongs "
+                "to one process. Measure in this process instead "
+                "(transport='inproc').")
         if max_attempts < 1:
             raise ValueError(f"max_attempts must be >= 1, got {max_attempts}")
         self.workers = workers

@@ -8,8 +8,8 @@ tile factors — the exact jitted wrappers deployment injects through — and
 times it with warmup + ``block_until_ready`` + median-of-reps
 (:mod:`repro.measure.timing`).
 
-Backend selection is automatic: on TPU/GPU the kernels compile natively
-and shapes are measured at full size; elsewhere Pallas runs in
+Backend selection is automatic: on TPU the kernels compile natively and
+shapes are measured at full size; elsewhere Pallas runs in
 ``interpret=True`` mode so the complete measure→reward→train loop runs in
 CI, with site dimensions capped (``max_dim``/``max_batch``) to keep the
 interpreted grids tractable.  Interpret-mode timings are a *proxy* — they
@@ -42,15 +42,15 @@ def _ceil_mult(x: int, m: int) -> int:
 
 
 def default_interpret() -> bool:
-    """Compiled kernels on TPU/GPU, interpret-mode Pallas elsewhere."""
-    return jax.default_backend() not in ("tpu", "gpu")
+    """Compiled kernels on TPU (the ``pltpu`` kernels target only it),
+    interpret-mode Pallas elsewhere."""
+    return jax.default_backend() != "tpu"
 
 
 def device_kind() -> str:
-    try:
-        return jax.devices()[0].device_kind
-    except Exception:
-        return "unknown"
+    """The first device's kind; raises when JAX has no device, so a
+    timing is never filed under an unknown backend."""
+    return jax.devices()[0].device_kind
 
 
 class MeasureRunner:
@@ -61,7 +61,7 @@ class MeasureRunner:
     reps, warmup: the timing loop (median of ``reps`` after ``warmup``
         discarded calls — the warmup also pays jit compilation).
     interpret:  force Pallas interpret mode; ``None`` auto-selects
-        (compiled on TPU/GPU, interpreted on CPU).
+        (compiled on TPU, interpreted elsewhere).
     max_dim, max_batch: per-dimension caps applied when interpreting
         (``None`` = auto: 128/2 interpreted, uncapped compiled).  Capped
         shapes are snapped to tile multiples, so every model-legal tile

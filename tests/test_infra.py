@@ -78,6 +78,59 @@ def test_trainer_restart_reproduces_loss(tmp_path):
     np.testing.assert_allclose(losses_resumed, losses_full[4:], rtol=1e-4)
 
 
+@pytest.mark.parametrize("env_dir", [None, "from-env"])
+def test_compile_cache_location(monkeypatch, tmp_path, env_dir):
+    """JAX_COMPILATION_CACHE_DIR, when set, is left to JAX; otherwise the
+    cache sits at the fixed ``<checkout>/.jax_cache``."""
+    from pathlib import Path
+
+    from repro.launch.compile_cache import enable_compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    if env_dir:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR",
+                           str(tmp_path / env_dir))
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    jax.config.update("jax_compilation_cache_dir", None)
+    try:
+        got = enable_compile_cache()
+        if env_dir:
+            assert got == str(tmp_path / env_dir)
+            assert jax.config.jax_compilation_cache_dir is None
+        else:
+            checkout = Path(__file__).resolve().parents[1]
+            assert got == str(checkout / ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == got
+            assert enable_compile_cache() == got       # fixed, not fresh
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
+def test_train_state_is_created_and_restored_sharded(tmp_path):
+    """The trainer's state is born with its mesh shardings, and a resumed
+    state lands on the same shardings with the saved values."""
+    from repro.launch.mesh import make_local_mesh
+    from repro.launch.train import init_state
+
+    model = build_model(get_config("stablelm_3b").reduced())
+    opt = adamw.AdamWConfig()
+    mesh = make_local_mesh(1)
+    mgr = CheckpointManager(str(tmp_path))
+    state, sh, step = init_state(model, opt, mesh, mgr)
+    assert step == 0
+    assert all(a.sharding == s for a, s in zip(jax.tree.leaves(state),
+                                               jax.tree.leaves(sh)))
+    assert sh["params"]["embed"].spec == jax.sharding.PartitionSpec(
+        "model", "data")
+    mgr.save(state, 3)
+    restored, sh2, step = init_state(model, opt, mesh, mgr)
+    assert step == 3 and sh2 == sh
+    for a, b, s in zip(jax.tree.leaves(restored), jax.tree.leaves(state),
+                       jax.tree.leaves(sh)):
+        assert a.sharding == s
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
 # ---------------------------------------------------------------------------
 # data pipeline
 # ---------------------------------------------------------------------------

@@ -273,6 +273,32 @@ def test_runner_backend_key_reflects_conditions():
     assert "interpret" in a
 
 
+@pytest.mark.parametrize("backend,interpret", [("tpu", False), ("gpu", True),
+                                               ("cpu", True)])
+def test_runner_compiles_kernels_only_on_tpu(monkeypatch, backend,
+                                             interpret):
+    """The pltpu kernels compile only for a TPU; every other backend
+    interprets them."""
+    import jax
+
+    from repro.measure.runner import default_interpret
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    assert default_interpret() is interpret
+
+
+def test_device_kind_raises_without_a_device(monkeypatch):
+    """No silent "unknown" backend: a timing must name its device."""
+    import jax
+
+    from repro.measure.runner import device_kind
+
+    def no_devices():
+        raise RuntimeError("no devices")
+    monkeypatch.setattr(jax, "devices", no_devices)
+    with pytest.raises(RuntimeError, match="no devices"):
+        device_kind()
+
+
 # ---------------------------------------------------------------------------
 # the assembled measured oracle
 # ---------------------------------------------------------------------------

@@ -197,6 +197,21 @@ def test_serve_rejects_bad_measure_flags():
                     "--program-store", "/tmp/x.jsonl"])
 
 
+def test_serve_measured_tune_on_analytic_fallback_exits_nonzero(monkeypatch):
+    """A measured tune whose every timing failed ends on the analytic
+    fallback (health 'degraded'): serve must fail, not serve that plan."""
+    from repro.launch import serve
+    from repro.measure.runner import MeasureRunner
+
+    monkeypatch.setattr(MeasureRunner, "measure_one",
+                        lambda self, site, tiles: float("inf"))
+    with pytest.raises(SystemExit) as exc:
+        serve.main(["--arch", "stablelm_3b", "--batch", "2",
+                    "--prompt-len", "8", "--gen", "2", "--autotune", "brute",
+                    "--measured"])
+    assert "health 'degraded'" in str(exc.value.code)
+
+
 def test_serve_warns_on_uncovered_sites(capsys):
     from repro.launch import serve
 

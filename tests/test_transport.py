@@ -271,6 +271,18 @@ def test_pool_rejects_bad_arguments():
                             factory="pool_helpers:no_such_factory")
 
 
+def test_pool_refuses_tpu_backend(monkeypatch):
+    """A chip belongs to one process: the pool refuses before it spawns."""
+    import threading
+
+    import jax
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    before = threading.active_count()
+    with pytest.raises(RuntimeError, match="one process"):
+        WorkerPoolTransport(workers=2)
+    assert threading.active_count() == before
+
+
 # ---------------------------------------------------------------------------
 # the factories and adapters around transports
 # ---------------------------------------------------------------------------
