@@ -1,0 +1,4 @@
+"""Device time per prefill step at baseline tiles over the agent's plan."""
+from harness import readers
+
+read = readers.plan_gain("prefill")
