@@ -13,6 +13,15 @@ label.  Three modes:
 * recording   — a :class:`SiteRecorder` is installed; tracing a step function
   (``jax.eval_shape``) registers every site with its concrete shapes/dtypes.
   This is the paper's *loop extractor* (DESIGN.md §2).
+
+Every call runs its ops, in every mode, under ``jax.named_scope("site=<site>")``:
+the operand pads, the GQA repeat, the ``pallas_call`` and its output slice in
+``pallas`` mode, the jnp ops in ``xla`` mode.  XLA keeps the scope in each
+instruction's ``op_name`` metadata, so the device ops an operator sees in a
+profile (TensorBoard, Perfetto) name their site, and the benchmark reads
+per-site device time from the same scope.  It is trace-time metadata and
+costs nothing at run time.  A fusion takes its root's ``op_name``: an op of
+the site fused into a consumer outside it is billed to the consumer.
 """
 from __future__ import annotations
 
@@ -146,15 +155,17 @@ def matmul(x: jax.Array, w: jax.Array, *, site: str,
         st.recorder.record(KernelSite(
             site=site, kind="matmul", m=M, n=int(N), k=int(K),
             dtype=str(x.dtype), fused_ops=fused_ops))
-    if st.mode == "pallas":
-        from repro.kernels import ops as kops
-        ksite = KernelSite(site=site, kind="matmul", m=M, n=int(N), k=int(K),
-                           dtype=str(x.dtype), fused_ops=fused_ops)
-        tiles = None if st.tiles is None else st.tiles.get(ksite.key())
-        x2 = x.reshape(M, K)
-        y = kops.matmul(x2, w, tiles=tiles, interpret=st.interpret)
-        return y.reshape(*lead, N)
-    return jnp.matmul(x, w)
+    with jax.named_scope(f"site={site}"):
+        if st.mode == "pallas":
+            from repro.kernels import ops as kops
+            ksite = KernelSite(site=site, kind="matmul", m=M, n=int(N),
+                               k=int(K), dtype=str(x.dtype),
+                               fused_ops=fused_ops)
+            tiles = None if st.tiles is None else st.tiles.get(ksite.key())
+            y = kops.matmul(x.reshape(M, K), w, tiles=tiles,
+                            interpret=st.interpret)
+            return y.reshape(*lead, N)
+        return jnp.matmul(x, w)
 
 
 def einsum(spec: str, *args, site: str) -> jax.Array:
@@ -173,7 +184,8 @@ def einsum(spec: str, *args, site: str) -> jax.Array:
         st.recorder.record(KernelSite(
             site=site, kind="matmul", m=m, n=n, k=k,
             dtype=str(args[0].dtype)))
-    return jnp.einsum(spec, *args)
+    with jax.named_scope(f"site={site}"):
+        return jnp.einsum(spec, *args)
 
 
 # ---------------------------------------------------------------------------
@@ -198,7 +210,6 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     """
     B, Hq, Sq, D = q.shape
     _, Hkv, Skv, _ = k.shape
-    Dv = v.shape[-1]        # MLA: v head dim may differ from qk head dim
     assert Hq % Hkv == 0
     st = _STATE
     if st.recorder is not None:
@@ -207,7 +218,19 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             dtype=str(q.dtype), causal=causal))
     if scale is None:
         scale = 1.0 / math.sqrt(D)
+    with jax.named_scope(f"site={site}"):
+        return _attention(q, k, v, site=site, causal=causal, q_chunk=q_chunk,
+                          kv_chunk=kv_chunk, scale=scale,
+                          base_offset=base_offset)
 
+
+def _attention(q, k, v, *, site, causal, q_chunk, kv_chunk, scale,
+               base_offset):
+    """The body of :func:`flash_attention`, inside its site scope."""
+    B, Hq, Sq, D = q.shape
+    _, Hkv, Skv, _ = k.shape
+    Dv = v.shape[-1]        # MLA: v head dim may differ from qk head dim
+    st = _STATE
     if st.mode == "pallas" and Sq > 1:
         from repro.kernels import ops as kops
         ksite = KernelSite(site=site, kind="attention", m=Sq, n=D, k=Skv,
