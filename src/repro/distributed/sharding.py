@@ -17,6 +17,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ModelConfig, ShapeConfig
+from repro.models import attention
 
 
 def dp_axes(mesh: Mesh):
@@ -184,10 +185,16 @@ def cache_specs(cfg: ModelConfig, shape: ShapeConfig, mesh: Mesh, cache_tree):
         seg = p.split("/")[-1]       # exact last key ("conv" must not match "v")
         nd = len(leaf.shape)
         # leading axis is the stacked period axis (scan) — unsharded
-        if seg in ("k", "v"):                            # (L,B,Hkv,S,hd)
-            if cfg.n_kv_heads >= mesh.shape["model"]:
-                return P(None, b, "model", "data" if seq_par else None, None)
-            return P(None, b, None, "data" if seq_par else "model", None)
+        if seg in ("k", "v"):
+            # the layouts attention.py gives a layer's self-attention cache
+            # and a cross-attention memory (lm.make_cache's "mem")
+            heads = cfg.n_kv_heads >= mesh.shape["model"]
+            axis = {"batch": b, "heads": "model" if heads else None,
+                    "ctx": "data" if seq_par else (None if heads else "model"),
+                    "head_dim": None}
+            axes = (attention.CROSS_CACHE_AXES if p.startswith("mem/")
+                    else attention.ATTN_CACHE_AXES)
+            return P(None, *(axis[a] for a in axes))
         if seg == "c_kv":                                # (L,B,S,r)
             return P(None, b, "data" if seq_par else None, "model")
         if seg == "k_rope":                              # (L,B,1,S,dr)

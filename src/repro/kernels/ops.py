@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.chunk_scan import chunk_scan_pallas
+from repro.kernels.decode_attention import decode_attention_pallas
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.matmul import matmul_pallas
 
@@ -58,6 +59,18 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     return flash_attention_pallas(q, k, v, causal=causal, scale=scale,
                                   block_q=bq, block_kv=bkv,
                                   interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
+                     k_cache: jax.Array, v_cache: jax.Array, layer, pos, *,
+                     scale: float, interpret: bool = False) -> tuple:
+    """q (B, Hq, hd) against layer ``layer`` of the stacked caches
+    (L, B, Hkv, hd, ctx), positions ``0 .. pos``, after writing this
+    position's k, v (B, Hkv, hd) there; no tunable factors.  Returns
+    (o, k_cache, v_cache)."""
+    return decode_attention_pallas(q, k, v, k_cache, v_cache, layer, pos,
+                                   scale=scale, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))

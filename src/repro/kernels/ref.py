@@ -23,6 +23,20 @@ def attention_ref(q: jax.Array, k: jax.Array, v: jax.Array, *, causal: bool,
     return jnp.einsum("bhqk,bhkd->bhqd", p.astype(v.dtype), v)
 
 
+def decode_attention_ref(q: jax.Array, k_cache: jax.Array,
+                         v_cache: jax.Array, layer: int, pos: int, *,
+                         scale: float) -> jax.Array:
+    """q: (B,Hq,D); k_cache, v_cache: (L,B,Hkv,D,ctx).  Positions 0..pos of
+    layer ``layer``, in float32."""
+    B, Hq, D = q.shape
+    Hkv = k_cache.shape[2]
+    k = k_cache[layer, ..., :pos + 1].astype(jnp.float32)
+    v = v_cache[layer, ..., :pos + 1].astype(jnp.float32)
+    qg = q.reshape(B, Hkv, Hq // Hkv, D).astype(jnp.float32)
+    p = jax.nn.softmax(jnp.einsum("bhgd,bhdk->bhgk", qg, k) * scale, axis=-1)
+    return jnp.einsum("bhgk,bhdk->bhgd", p, v).reshape(B, Hq, D)
+
+
 def chunk_scan_ref(x: jax.Array, Bm: jax.Array, Cm: jax.Array,
                    la: jax.Array) -> jax.Array:
     """Sequential oracle for the SSD scan.  x (G,S,P); Bm/Cm (G,S,N);

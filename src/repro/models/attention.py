@@ -3,7 +3,6 @@ from __future__ import annotations
 
 from typing import Optional
 
-import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
@@ -38,13 +37,15 @@ def _merge_heads(x):
 
 
 def apply_attn(cfg: ModelConfig, p, x, *, positions, causal: bool,
-               cache: Optional[dict] = None, decode_pos=None,
+               cache: Optional[dict] = None, decode_pos=None, layer=None,
                site_prefix: str = "attn"):
     """Self-attention.
 
-    Train/prefill: ``cache is None`` or a zeroed cache to fill (prefill).
-    Decode: ``cache`` holds (B, Hkv, S_ctx, hd) k/v; ``decode_pos`` is the
-    scalar write position.  Returns (y, new_cache_or_None).
+    Train/prefill: ``cache is None`` or a zeroed (B, Hkv, hd, S_ctx) cache
+    to fill (prefill), ctx last.
+    Decode: ``cache`` holds the layer scan's stacked (L, B, Hkv, hd, S_ctx)
+    k/v and ``layer`` the scan index; ``decode_pos`` is the scalar write
+    position.  Returns (y, new_cache_or_None).
     """
     hq, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     q = _split_heads(compute.matmul(x, p["wq"], site=f"{site_prefix}.q"), hq, hd)
@@ -59,20 +60,18 @@ def apply_attn(cfg: ModelConfig, p, x, *, positions, causal: bool,
     k = apply_rope(k, positions, cfg.rope_theta, cfg.rope)
 
     new_cache = None
-    base_offset = 0
     if cache is not None and decode_pos is not None:
-        # decode: write this step's k/v at decode_pos, attend over full cache
-        ck = jax.lax.dynamic_update_slice_in_dim(cache["k"], k, decode_pos, axis=2)
-        cv = jax.lax.dynamic_update_slice_in_dim(cache["v"], v, decode_pos, axis=2)
-        k, v = ck, cv
-        new_cache = {"k": ck, "v": cv}
-        base_offset = decode_pos
-    elif cache is not None:
-        # prefill: fill the cache with the computed k/v
-        new_cache = {"k": k, "v": v}
-
-    o = compute.flash_attention(q, k, v, site=f"{site_prefix}.core",
-                                causal=causal, base_offset=base_offset)
+        # decode: write this step's k/v at decode_pos, attend up to it
+        o, new_cache = compute.decode_attention(
+            q, k, v, cache, pos=decode_pos, site=f"{site_prefix}.core",
+            layer=layer)
+    else:
+        if cache is not None:
+            # prefill: fill the cache with the computed k/v, ctx last
+            new_cache = {"k": jnp.swapaxes(k, -1, -2),
+                         "v": jnp.swapaxes(v, -1, -2)}
+        o = compute.flash_attention(q, k, v, site=f"{site_prefix}.core",
+                                    causal=causal)
     y = compute.matmul(_merge_heads(o), p["wo"], site=f"{site_prefix}.o")
     return y, new_cache
 
@@ -98,7 +97,26 @@ def apply_cross_attn(cfg: ModelConfig, p, x, *, memory=None,
     return y, mem_cache
 
 
+#: Axes of one layer's self-attention K/V cache, ctx last: the order the
+#: TPU compiler keeps it in, which the decode kernel reads in place.
+ATTN_CACHE_AXES = ("batch", "heads", "head_dim", "ctx")
+#: Axes of a cross-attention memory's K/V, as the encoder computes them.
+CROSS_CACHE_AXES = ("batch", "heads", "ctx", "head_dim")
+
+
+def _kv_zeros(cfg: ModelConfig, axes, batch: int, ctx: int, dtype):
+    size = {"batch": batch, "heads": cfg.n_kv_heads, "head_dim": cfg.head_dim,
+            "ctx": ctx}
+    shape = tuple(size[a] for a in axes)
+    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+
+
 def make_attn_cache(cfg: ModelConfig, batch: int, ctx: int, dtype):
-    hkv, hd = cfg.n_kv_heads, cfg.head_dim
-    return {"k": jnp.zeros((batch, hkv, ctx, hd), dtype),
-            "v": jnp.zeros((batch, hkv, ctx, hd), dtype)}
+    """Self-attention K/V: (B, Hkv, hd, ctx), ``ATTN_CACHE_AXES``."""
+    return _kv_zeros(cfg, ATTN_CACHE_AXES, batch, ctx, dtype)
+
+
+def make_cross_cache(cfg: ModelConfig, batch: int, ctx: int, dtype):
+    """Cross-attention memory K/V: (B, Hkv, S_src, hd),
+    ``CROSS_CACHE_AXES``."""
+    return _kv_zeros(cfg, CROSS_CACHE_AXES, batch, ctx, dtype)

@@ -46,8 +46,9 @@ def block_cache(cfg: ModelConfig, b: BlockDesc, batch: int, ctx: int, dtype):
 
 def block_apply(cfg: ModelConfig, b: BlockDesc, p, x, *, positions,
                 causal: bool = True, cache: Optional[dict] = None,
-                decode_pos=None):
-    """Returns (x, new_cache, aux)."""
+                decode_pos=None, layer=None):
+    """Returns (x, new_cache, aux).  ``layer``: the layer scan's index when
+    ``cache`` is the stacked self-attention cache (decode)."""
     h = apply_norm(cfg, p["norm1"], x)
     if b.kind == "attn":
         if cfg.mla:
@@ -57,7 +58,8 @@ def block_apply(cfg: ModelConfig, b: BlockDesc, p, x, *, positions,
         else:
             y, nc = attention.apply_attn(cfg, p["mixer"], h,
                                          positions=positions, causal=causal,
-                                         cache=cache, decode_pos=decode_pos)
+                                         cache=cache, decode_pos=decode_pos,
+                                         layer=layer)
     elif b.kind == "mamba":
         y, nc = ssm.apply_ssm(cfg, p["mixer"], h, cache=cache,
                               decode_pos=decode_pos)

@@ -2,8 +2,8 @@
 
 NeuroVectorizer injects ``#pragma clang loop vectorize_width(VF)
 interleave_count(IF)`` above each loop.  Here, every tunable hot op in the
-model zoo goes through :func:`matmul` / :func:`flash_attention` with a *site*
-label.  Three modes:
+model zoo goes through :func:`matmul` / :func:`flash_attention` /
+:func:`decode_attention` with a *site* label.  Three modes:
 
 * ``xla``     — plain jnp ops (the default; what the dry-run lowers).
 * ``pallas``  — route through the Pallas kernels in ``repro.kernels`` using
@@ -15,8 +15,9 @@ label.  Three modes:
   This is the paper's *loop extractor* (DESIGN.md §2).
 
 Every call runs its ops, in every mode, under ``jax.named_scope("site=<site>")``:
-the operand pads, the GQA repeat, the ``pallas_call`` and its output slice in
-``pallas`` mode, the jnp ops in ``xla`` mode.  XLA keeps the scope in each
+the operand pads, the GQA repeat, the decode cache's column write, the
+``pallas_call`` and its output slice in ``pallas`` mode, the jnp ops in
+``xla`` mode.  XLA keeps the scope in each
 instruction's ``op_name`` metadata, so the device ops an operator sees in a
 profile (TensorBoard, Perfetto) name their site, and the benchmark reads
 per-site device time from the same scope.  It is trace-time metadata and
@@ -196,13 +197,12 @@ def einsum(spec: str, *args, site: str) -> jax.Array:
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     site: str, causal: bool,
                     q_chunk: int = 1024, kv_chunk: int = 2048,
-                    scale: Optional[float] = None,
-                    base_offset=0) -> jax.Array:
+                    scale: Optional[float] = None) -> jax.Array:
     """Memory-chunked attention.
 
     q: (B, Hq, Sq, D); k/v: (B, Hkv, Skv, D) with Hq % Hkv == 0 (GQA).
-    ``base_offset``: absolute position of q[0] (for causal decode masking);
-    may be a traced scalar.
+    Causal masks are bottom-right aligned: the last query sees every key.
+    Self-attention decode goes through :func:`decode_attention`.
 
     In ``pallas`` mode routes to the flash-attention kernel with tuned
     (block_q, block_kv); in ``xla`` mode runs the same algorithm with
@@ -220,12 +220,59 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         scale = 1.0 / math.sqrt(D)
     with jax.named_scope(f"site={site}"):
         return _attention(q, k, v, site=site, causal=causal, q_chunk=q_chunk,
-                          kv_chunk=kv_chunk, scale=scale,
-                          base_offset=base_offset)
+                          kv_chunk=kv_chunk, scale=scale)
 
 
-def _attention(q, k, v, *, site, causal, q_chunk, kv_chunk, scale,
-               base_offset):
+def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array, cache: dict,
+                     *, pos, layer, site: str) -> tuple:
+    """One decode position of causal self-attention against layer
+    ``layer`` of the layer scan's stacked K/V cache, in its stored layout,
+    ctx last: ``cache["k"]``, ``cache["v"]`` are ``(L, B, Hkv, hd, ctx)``.
+
+    q: (B, Hq, 1, hd); k, v: (B, Hkv, 1, hd), this position's keys and
+    values, written into the stack at ``(layer, ..., pos)`` before it is
+    read.  Returns (o (B, Hq, 1, hd), the written stack).
+
+    Recorded as the same attention site as :func:`flash_attention` with
+    ``Sq == 1``.  In ``pallas`` mode one decode-kernel call writes the
+    column into the stack in place and reads the layer where it lies; in
+    ``xla`` mode a plain einsum reads the layer, sliced out and written
+    back.
+    """
+    B, Hq, _, D = q.shape
+    Hkv, Skv = k.shape[1], cache["k"].shape[-1]
+    st = _STATE
+    if st.recorder is not None:
+        st.recorder.record(KernelSite(
+            site=site, kind="attention", m=1, n=D, k=Skv, batch=B * Hq,
+            dtype=str(q.dtype), causal=True))
+    scale = 1.0 / math.sqrt(D)
+    with jax.named_scope(f"site={site}"):
+        if st.mode == "pallas":
+            from repro.kernels import ops as kops
+            o, kc, vc = kops.decode_attention(
+                q[:, :, 0], k[:, :, 0], v[:, :, 0], cache["k"], cache["v"],
+                layer, pos, scale=scale, interpret=st.interpret)
+            return o[:, :, None], {"k": kc, "v": vc}
+        # the column goes in by a select over the layer: XLA lays out a
+        # cache written by a one-column dynamic-update-slice with ctx major,
+        # and relayouts the whole stacked carry to do so
+        hit = jnp.arange(Skv) == pos
+        kv = {n: jnp.where(hit, jnp.swapaxes(x, -1, -2).astype(
+            cache[n].dtype), jax.lax.dynamic_index_in_dim(
+                cache[n], layer, 0, keepdims=False))
+            for n, x in (("k", k), ("v", v))}
+        qg = q.reshape(B, Hkv, Hq // Hkv, D)
+        s = jnp.einsum("bhgd,bhdk->bhgk", qg, kv["k"]).astype(jnp.float32)
+        s = jnp.where(jnp.arange(Skv) <= pos, s * scale, -jnp.inf)
+        p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
+        o = jnp.einsum("bhgk,bhdk->bhgd", p, kv["v"])
+        new = {n: jax.lax.dynamic_update_index_in_dim(cache[n], kv[n], layer,
+                                                      0) for n in kv}
+    return o.reshape(B, Hq, 1, D), new
+
+
+def _attention(q, k, v, *, site, causal, q_chunk, kv_chunk, scale):
     """The body of :func:`flash_attention`, inside its site scope."""
     B, Hq, Sq, D = q.shape
     _, Hkv, Skv, _ = k.shape
@@ -257,12 +304,9 @@ def _attention(q, k, v, *, site, causal, q_chunk, kv_chunk, scale,
     if Sq == 1:
         group = Hq // Hkv
         qf = q.reshape(B, Hkv, group, Sq, D)
-        # decode: single position, no chunking needed in q
+        # one query (cross-attention decode): it sees every key, causal
+        # or not, and needs no chunking
         s = jnp.einsum("bhgqd,bhkd->bhgqk", qf, k).astype(jnp.float32) * scale
-        if causal:
-            kpos = jnp.arange(Skv)
-            mask = kpos[None, :] <= (base_offset + jnp.arange(Sq))[:, None]
-            s = jnp.where(mask[None, None, None], s, -jnp.inf)
         p = jax.nn.softmax(s, axis=-1).astype(v.dtype)
         o = jnp.einsum("bhgqk,bhkd->bhgqd", p, v)
         return o.reshape(B, Hq, Sq, Dv)

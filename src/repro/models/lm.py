@@ -94,12 +94,19 @@ def _run_stack(cfg: ModelConfig, stack_params, x, *, positions, causal,
     dynamic_update_index (XLA aliases while-loop carry buffers), never as
     xs->ys — emitting updated caches as scan outputs allocates a full fresh
     copy of every cache per step (measured +2x cache bytes of pure temp on
-    the 32k-decode cells)."""
+    the 32k-decode cells).
+
+    Decoding, a self-attention slot gets its whole stacked cache and the
+    scan index instead of a slice, and returns the stack written: in
+    ``pallas`` mode the decode kernel writes and reads the layer where it
+    lies.  Sliced out and written back around a Pallas call, each layer's
+    K/V would be relayouted and copied whole every step."""
     has_cache = caches is not None
     has_mem = memory is not None or mem_caches is not None
     # cross-attn k/v is written only on prefill (cache fill); train
     # recomputes it under remat and decode reuses the cache passed in.
     write_mem = has_mem and mem_caches is None and has_cache
+    decode_attn = has_cache and decode_pos is not None and not cfg.mla
 
     from jax.sharding import PartitionSpec as _P
 
@@ -127,12 +134,16 @@ def _run_stack(cfg: ModelConfig, stack_params, x, *, positions, causal,
         new_mem = list(mem_c) if mem_c is not None else None
         for slot, b in enumerate(cfg.period):
             pp = slot_params[slot]
-            cs = _slice(caches_c[slot], idx) if has_cache else None
+            stack = decode_attn and b.kind == "attn"
+            cs = None
+            if has_cache:
+                cs = caches_c[slot] if stack else _slice(caches_c[slot], idx)
             x, nc, aux = blocks.block_apply(
                 cfg, b, pp, x, positions=positions, causal=causal,
-                cache=cs, decode_pos=decode_pos)
+                cache=cs, decode_pos=decode_pos, layer=idx if stack else None)
             if has_cache:
-                new_caches[slot] = _update(new_caches[slot], nc, idx)
+                new_caches[slot] = nc if stack else _update(
+                    new_caches[slot], nc, idx)
             if has_mem:
                 hx = apply_norm(cfg, pp["norm_x"], x)
                 mc = _slice(mem_c[slot], idx) if mem_caches is not None \
@@ -300,7 +311,7 @@ def make_cache(cfg: ModelConfig, batch: int, ctx: int, dtype):
     out = {"caches": caches}
     if cfg.enc_dec:
         out["mem"] = tuple(
-            stacked(lambda: attention.make_attn_cache(cfg, batch, ctx, dtype))
+            stacked(lambda: attention.make_cross_cache(cfg, batch, ctx, dtype))
             for _ in cfg.period)
     return out
 

@@ -295,6 +295,38 @@ def test_param_specs_cover_all_archs():
             assert len(sp) <= len(sh.shape), (sh.shape, sp)
 
 
+@pytest.mark.parametrize("batch,model_par,heads_axis,ctx_axis", [
+    (1, 2, "model", "data"),   # batch 1 over 2 data shards: sequence parallel
+    (4, 8, None, "model"),     # 4 kv heads over 8 model shards: ctx over model
+])
+def test_cache_specs_shard_ctx_where_each_cache_keeps_it(
+        batch, model_par, heads_axis, ctx_axis):
+    # an enc-dec config holds both layouts: the decoder's self-attention
+    # cache (L, B, Hkv, hd, ctx) and the cross-attention memory
+    # (L, B, Hkv, S, hd)
+    from jax.sharding import PartitionSpec as P
+
+    class FakeMesh:
+        axis_names = ("data", "model")
+        shape = {"data": 2, "model": model_par}
+
+    cfg = get_config("seamless_m4t_medium").reduced()
+    ctx = 96
+    cache = jax.eval_shape(
+        lambda: build_model(cfg).make_cache(batch, ctx, jnp.bfloat16))
+    specs = shd.cache_specs(cfg, ShapeConfig("t", ctx, batch, "decode"),
+                            FakeMesh(), cache)
+    for part, ctx_dim in (("caches", 4), ("mem", 3)):
+        for name in ("k", "v"):
+            leaf, spec = cache[part][0][name], specs[part][0][name]
+            assert leaf.shape[ctx_dim] == ctx
+            want = [None] * 5
+            want[ctx_dim] = ctx_axis
+            want[1] = None if ctx_axis == "data" else ("data",)
+            want[2] = heads_axis
+            assert spec == P(*want), (part, name, spec)
+
+
 def test_fit_spec_drops_indivisible_axes():
     from jax.sharding import PartitionSpec as P
     mesh = jax.make_mesh((1,), ("model",))

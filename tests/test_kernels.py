@@ -111,6 +111,45 @@ def test_flash_property(sq, d, bq, bkv, causal):
 
 
 # ---------------------------------------------------------------------------
+# decode attention over the stacked cache
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("hq,hkv,d", [(32, 32, 80), (32, 2, 128)],
+                         ids=["mha_hd80", "gqa16_hd128"])
+@pytest.mark.parametrize("pos", [0, 127, 128, 383])
+@pytest.mark.parametrize("ctx", [384, 200])
+def test_decode_attention(hq, hkv, d, pos, ctx):
+    """ctx 200 ends in a partial block: positions 200..255 of its last
+    block lie past the caches (NaN in interpret mode) and must not leak."""
+    from repro.kernels.decode_attention import BK
+
+    L, B, layer = 3, 2, 1
+    assert BK == 128                              # pos 127 and 128 straddle
+    pos = min(pos, ctx - 1)
+    key = jax.random.PRNGKey(pos)
+    q = jax.random.normal(key, (B, hq, d)).astype(jnp.bfloat16)
+    k, v = (jax.random.normal(jax.random.fold_in(key, i),
+                              (L, B, hkv, d, ctx)).astype(jnp.bfloat16)
+            for i in (1, 2))
+    # what the kernel must not read: positions past pos (the previous
+    # batch's keys) and the other layers; at pos, it writes this step's
+    # key and value
+    stale = (jnp.arange(ctx) >= pos) | (jnp.arange(L) != layer)[
+        :, None, None, None, None]
+    k_new, v_new = k[layer, ..., pos], v[layer, ..., pos]
+    y, k_out, v_out = ops.decode_attention(
+        q, k_new, v_new, jnp.where(stale, 1e4, k), jnp.where(stale, 1e4, v),
+        layer, pos, scale=d ** -0.5, interpret=True)
+    yr = ref.decode_attention_ref(q, k, v, layer, pos, scale=d ** -0.5)
+    assert y.shape == q.shape and y.dtype == q.dtype
+    assert float(jnp.max(jnp.abs(y.astype(jnp.float32) - yr))) < 2e-2
+    # the caches come back with the column written and nothing else moved
+    for got, new, old in ((k_out, k_new, k), (v_out, v_new, v)):
+        want = jnp.where(stale, 1e4, old).at[layer, ..., pos].set(new)
+        assert bool(jnp.all(got == want))
+
+
+# ---------------------------------------------------------------------------
 # chunk scan (SSD)
 # ---------------------------------------------------------------------------
 

@@ -79,6 +79,43 @@ def test_arch_decode_matches_prefill(arch, monkeypatch):
                                rtol=2e-2, atol=2e-2)
 
 
+@pytest.mark.parametrize("arch,overrides", [
+    ("stablelm_3b", dict(head_dim=80)),
+    ("chatglm3_6b", dict(n_heads=32, n_kv_heads=2, head_dim=128)),
+], ids=["mha_hd80", "gqa16_hd128"])
+def test_pallas_decode_matches_xla(arch, overrides):
+    """Decode steps through the decode kernel on the stacked cache (Pallas,
+    interpret mode) give the plain XLA path's logits and cache, across a
+    kernel block boundary (positions 126-128 of a 384-position cache)."""
+    from repro.models import compute
+    cfg = get_config(arch).reduced(n_layers=2, **overrides)
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    B, P, ctx = 2, 126, 384
+    toks = _smoke_batch(cfg, B, P)["tokens"]
+
+    def run(mode):
+        with compute.compute_mode(mode, interpret=True):
+            prefill = jax.jit(model.prefill)
+            decode = jax.jit(model.decode_step)
+            logits, cache = prefill(params, {"tokens": toks},
+                                    model.make_cache(B, ctx, jnp.float32))
+            out = []
+            for pos in range(P, P + 3):
+                tok = jnp.argmax(logits, -1).astype(jnp.int32)[:, None]
+                logits, cache = decode(params, tok, jnp.int32(pos), cache)
+                out.append(logits)
+        return out, cache
+
+    (want, want_cache), (got, got_cache) = run("xla"), run("pallas")
+    for w, g in zip(want, got):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=1e-4, atol=1e-4)
+    for w, g in zip(jax.tree.leaves(want_cache), jax.tree.leaves(got_cache)):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=1e-4, atol=1e-4)
+
+
 def test_moe_capacity_drops_are_bounded():
     from repro.models import moe
     cfg = get_config("jamba_v0_1_52b").reduced(n_experts=4, moe_top_k=2,
