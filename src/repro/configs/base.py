@@ -59,10 +59,18 @@ class ModelConfig:
     tie_embeddings: bool = False
 
     # --- MoE ---
-    n_experts: int = 0
+    n_experts: int = 0             # routed experts (the router's width)
     n_shared_experts: int = 0
     moe_top_k: int = 0
     moe_d_ff: int = 0              # per-expert hidden size
+    n_dense_layers: int = 0        # leading dense layers before the period
+    expert_offset: int = 0         # first routed expert held here
+    n_held_experts: int = 0        # routed experts held here (0 = all)
+    shared_d_ff: int = 0           # shared experts' width (0 = n_shared * moe_d_ff)
+    n_group: int = 1               # routing groups (group-limited greedy)
+    topk_group: int = 1            # groups a token may route into
+    routed_scaling_factor: float = 1.0
+    norm_topk_prob: bool = True    # renormalise the top-k gates to sum 1
 
     # --- MLA (DeepSeek-V2) ---
     mla: bool = False
@@ -71,6 +79,11 @@ class ModelConfig:
     qk_nope_dim: int = 0
     qk_rope_dim: int = 0
     v_head_dim: int = 0
+    # YaRN rotary scaling of the rope slice (factor 0 = none); the softmax
+    # scale gains mscale(factor, yarn_mscale)^2
+    yarn_factor: float = 0.0
+    yarn_original_ctx: int = 4096
+    yarn_mscale: float = 0.0
 
     # --- SSM (Mamba-style, SSD formulation) ---
     ssm_state_dim: int = 128
@@ -106,14 +119,31 @@ class ModelConfig:
         if self.head_dim == 0:
             object.__setattr__(self, "head_dim", self.d_model // self.n_heads)
         if not self.enc_dec:
-            assert self.n_layers % len(self.period) == 0, (
-                f"{self.name}: n_layers={self.n_layers} not divisible by "
-                f"period of {len(self.period)}")
+            assert (self.n_layers - self.n_dense_layers) % len(self.period) \
+                == 0, (f"{self.name}: n_layers={self.n_layers} less "
+                       f"{self.n_dense_layers} dense not divisible by "
+                       f"period of {len(self.period)}")
+        if self.n_experts:
+            assert self.n_experts % self.n_group == 0, self.n_group
+            assert self.expert_offset + self.held_experts <= self.n_experts
 
     # ------------------------------------------------------------------
     @property
     def n_periods(self) -> int:
-        return self.n_layers // len(self.period)
+        return (self.n_layers - self.n_dense_layers) // len(self.period)
+
+    @property
+    def dense_block(self) -> BlockDesc:
+        """The leading dense layers' block: the period's mixer, dense MLP."""
+        return BlockDesc(self.period[0].kind, "dense")
+
+    @property
+    def held_experts(self) -> int:
+        return self.n_held_experts or self.n_experts
+
+    @property
+    def shared_width(self) -> int:
+        return self.shared_d_ff or self.n_shared_experts * self.moe_d_ff
 
     @property
     def d_head_total(self) -> int:
@@ -153,7 +183,8 @@ class ModelConfig:
     def reduced(self, **overrides) -> "ModelConfig":
         """A tiny same-family config for CPU smoke tests."""
         small = dict(
-            n_layers=len(self.period),
+            n_layers=min(self.n_dense_layers, 1) + len(self.period),
+            n_dense_layers=min(self.n_dense_layers, 1),
             d_model=64,
             n_heads=4,
             n_kv_heads=min(self.n_kv_heads, 4) if self.n_kv_heads > 1 else 1,
@@ -164,6 +195,11 @@ class ModelConfig:
             moe_top_k=min(self.moe_top_k, 2),
             n_shared_experts=min(self.n_shared_experts, 1),
             moe_d_ff=64 if self.moe_d_ff else 0,
+            expert_offset=0,
+            n_held_experts=0,
+            shared_d_ff=0,
+            n_group=min(self.n_group, 2),
+            topk_group=1,
             kv_lora_rank=32 if self.mla else 0,
             q_lora_rank=48 if (self.mla and self.q_lora_rank) else 0,
             qk_nope_dim=16 if self.mla else 0,
@@ -214,8 +250,9 @@ def _count_params(c: ModelConfig, active_only: bool = False) -> int:
 
     def moe_mlp_params(active: bool) -> int:
         mult = 3 if _gated(c.act) else 2
-        n_routed = c.moe_top_k if active else c.n_experts
-        p = (n_routed + c.n_shared_experts) * mult * d * c.moe_d_ff
+        n_routed = c.moe_top_k if active else c.held_experts
+        p = n_routed * mult * d * c.moe_d_ff
+        p += mult * d * c.shared_width
         p += d * c.n_experts                                      # router
         return p
 
@@ -248,9 +285,11 @@ def _count_params(c: ModelConfig, active_only: bool = False) -> int:
             p += moe_mlp_params(active)
         return p
 
-    n_units = (c.n_enc_layers + c.n_dec_layers) if c.enc_dec else c.n_layers
+    n_units = ((c.n_enc_layers + c.n_dec_layers) if c.enc_dec
+               else c.n_layers - c.n_dense_layers)
     per_period = sum(block_params(b, active_only) for b in c.period)
     total += per_period * (n_units // len(c.period))
+    total += c.n_dense_layers * block_params(c.dense_block, active_only)
     if c.enc_dec:   # cross-attention in decoder layers
         total += c.n_dec_layers * attn_params()
     return int(total)

@@ -13,6 +13,7 @@ from __future__ import annotations
 import math
 from typing import Optional, Tuple
 
+from repro.kernels.grouped_matmul import fit_tile   # the kernel's bn, bk
 from repro.models.compute import KernelSite
 
 # ---- TPU v5e hardware constants (also used by the roofline analysis) ----
@@ -101,6 +102,43 @@ def baseline_matmul_tiles(M: int, N: int, K: int) -> Tuple[int, int, int]:
 
 
 # ===========================================================================
+# grouped matmul (ragged groups: the held experts of an MoE layer)
+# ===========================================================================
+
+def grouped_matmul_cost(site: KernelSite,
+                        tiles: Tuple[int, int, int]) -> Optional[float]:
+    """Site semantics: m = rows all groups expect together, batch = G
+    groups of ceil(m / G) rows each.  Each group's rows are padded to
+    ``bm``; each row tile reads its group's (K, N) weights once, so a
+    group whose rows fit one tile reads its weights once."""
+    M, N, K, G = site.m, site.n, site.k, site.batch
+    bm, bn, bk = tiles
+    s = _dtype_bytes(site.dtype)
+    if bm <= 0 or bn <= 0 or bk <= 0:
+        return None
+    bn, bk = fit_tile(N, bn), fit_tile(K, bk)
+    vmem = 2 * (bm * bk + bk * bn) * s + bm * bn * 4 + bm * bn * s
+    if vmem > VMEM_BYTES:
+        return None
+    tiles_m = G * _ceil(_ceil(M, G), bm)
+    tn, tk = N // bn, K // bk
+    grid = tiles_m * tn * tk
+    pm = tiles_m * bm
+    flops = 2.0 * pm * N * K
+    t_compute = flops / (_peak(site.dtype) * _mxu_util(bm, bn, bk))
+    bytes_ = pm * K * tn * s + tiles_m * K * N * s + pm * N * s
+    t_mem = bytes_ / HBM_BW
+    return (max(t_compute, t_mem) + grid * GRID_STEP_OVERHEAD
+            + FIXED_OVERHEAD)
+
+
+def baseline_grouped_tiles(M: int, N: int, K: int,
+                           G: int) -> Tuple[int, int, int]:
+    """The matmul heuristic at one group's rows."""
+    return baseline_matmul_tiles(_ceil(M, G), N, K)
+
+
+# ===========================================================================
 # attention (flash)
 # ===========================================================================
 
@@ -179,6 +217,8 @@ def baseline_chunk(S: int) -> Tuple[int]:
 def site_cost(site: KernelSite, tiles: Tuple[int, ...]) -> Optional[float]:
     if site.kind == "matmul":
         return matmul_cost(site, tiles[:3])
+    if site.kind == "grouped_matmul":
+        return grouped_matmul_cost(site, tiles[:3])
     if site.kind == "attention":
         return attention_cost(site, tiles[:2])
     if site.kind == "chunk_scan":
@@ -189,6 +229,8 @@ def site_cost(site: KernelSite, tiles: Tuple[int, ...]) -> Optional[float]:
 def baseline_tiles(site: KernelSite) -> Tuple[int, ...]:
     if site.kind == "matmul":
         return baseline_matmul_tiles(site.m, site.n, site.k)
+    if site.kind == "grouped_matmul":
+        return baseline_grouped_tiles(site.m, site.n, site.k, site.batch)
     if site.kind == "attention":
         return baseline_attn_tiles(site.m, site.k)
     if site.kind == "chunk_scan":

@@ -75,6 +75,36 @@ def matmul_cost_vec(M, N, K, s, peak, bm, bn, bk) -> np.ndarray:
     return np.where(legal, cost, ILLEGAL)
 
 
+def fit_tile_vec(dim, t) -> np.ndarray:
+    """Broadcasted ``costmodel.fit_tile``."""
+    dim, t = np.broadcast_arrays(np.asarray(dim, np.int64),
+                                 np.asarray(t, np.int64))
+    top = max(int(t.max(initial=0)) // cm.LANE, 1)
+    b = np.arange(top, 0, -1, dtype=np.int64) * cm.LANE     # descending
+    ok = (b <= t[..., None]) & (dim[..., None] % b == 0)
+    best = np.where(ok.any(-1), b[np.argmax(ok, axis=-1)], dim)
+    return np.where(dim <= t, dim, best)
+
+
+def grouped_matmul_cost_vec(M, N, K, G, s, peak, bm, bn, bk) -> np.ndarray:
+    """Broadcasted ``grouped_matmul_cost`` (same op order)."""
+    bn, bk = fit_tile_vec(N, bn), fit_tile_vec(K, bk)
+    vmem = 2 * (bm * bk + bk * bn) * s + bm * bn * 4 + bm * bn * s
+    legal = vmem <= cm.VMEM_BYTES
+    tiles_m = G * _ceil(_ceil(M, G), bm)
+    tn, tk = N // bn, K // bk
+    grid = tiles_m.astype(np.float64) * tn * tk
+    pm = (tiles_m * bm).astype(np.float64)
+    flops = 2.0 * pm * N * K
+    t_compute = flops / (peak * _mxu_util_vec(bm, bn, bk))
+    bytes_ = pm * K * tn * s + tiles_m.astype(np.float64) * K * N * s \
+        + pm * N * s
+    t_mem = bytes_ / cm.HBM_BW
+    cost = (np.maximum(t_compute, t_mem) + grid * cm.GRID_STEP_OVERHEAD
+            + cm.FIXED_OVERHEAD)
+    return np.where(legal, cost, ILLEGAL)
+
+
 def attention_cost_vec(Sq, Skv, D, BH, causal, s, peak, bq, bkv) -> np.ndarray:
     tq, tkv = _ceil(Sq, bq), _ceil(Skv, bkv)
     vmem = 2 * (bq * D + 2 * bkv * D) * s + bq * D * 4 + 2 * bq * 4 \
@@ -161,6 +191,9 @@ def _cost_kind(kind: str, c: Dict[str, np.ndarray],
     if kind == "matmul":
         return matmul_cost_vec(c["m"], c["n"], c["k"], c["s"], c["peak"],
                                t0, t1, t2)
+    if kind == "grouped_matmul":
+        return grouped_matmul_cost_vec(c["m"], c["n"], c["k"], c["batch"],
+                                       c["s"], c["peak"], t0, t1, t2)
     if kind == "attention":
         # site semantics: m=Sq, k=Skv, n=D, batch=B*H
         return attention_cost_vec(c["m"], c["k"], c["n"], c["batch"],
@@ -319,7 +352,10 @@ def baseline_tiles_batch(sites: Sequence[KernelSite]) -> np.ndarray:
         M = np.array([sites[i].m for i in idx], np.int64)
         N = np.array([sites[i].n for i in idx], np.int64)
         K = np.array([sites[i].k for i in idx], np.int64)
-        if kind == "matmul":
+        if kind == "grouped_matmul":
+            G = np.array([sites[i].batch for i in idx], np.int64)
+            M = _ceil(M, G)                         # one group's rows
+        if kind in ("matmul", "grouped_matmul"):
             out[idx, 0] = np.minimum(128, _ceil(M, cm.SUBLANE) * cm.SUBLANE)
             out[idx, 1] = np.minimum(128, _ceil(N, cm.LANE) * cm.LANE)
             out[idx, 2] = np.minimum(512, _ceil(K, cm.LANE) * cm.LANE)

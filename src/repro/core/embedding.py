@@ -76,6 +76,13 @@ def _leaf_tokens(site: KernelSite) -> List[Tuple[str, str]]:
     return leaves
 
 
+def _path_kind(site: KernelSite) -> str:
+    """The root of a site's paths.  A grouped matmul is a matmul with a
+    batch of groups: it shares the matmul paths (and the vocabulary and
+    the embedder's shapes stay those of the three primitive kinds)."""
+    return "matmul" if site.kind == "grouped_matmul" else site.kind
+
+
 # featurization is a pure function of the site, and training resamples the
 # same corpus sites every batch — memoize (read-only arrays; bounded)
 _FEAT_CACHE: dict = {}
@@ -97,7 +104,7 @@ def featurize(site: KernelSite,
     ctxs = []
     for (ta, ca), (tb, cb) in itertools.combinations(leaves, 2):
         pa, pb = sorted((ca, cb))
-        path = f"{site.kind}|{pa}-{pb}"
+        path = f"{_path_kind(site)}|{pa}-{pb}"
         ctxs.append((_VOCAB[ta], _PATH_IDX.get(path, 0), _VOCAB[tb]))
     # deterministic subsample to MAX_PATHS (keep coverage of all leaves)
     if len(ctxs) > MAX_PATHS:

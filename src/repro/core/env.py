@@ -52,7 +52,7 @@ class ActionSpace:
 
     def choices(self, kind: str) -> Tuple[Tuple[int, ...], ...]:
         c = self.cfg
-        if kind == "matmul":
+        if kind in ("matmul", "grouped_matmul"):
             return (c.bm_choices, c.bn_choices, c.bk_choices)
         if kind == "attention":
             return (c.bq_choices, c.bkv_choices, (1,))

@@ -65,17 +65,19 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
                            causal: bool, scale: float, block_q: int,
                            block_kv: int,
                            interpret: bool = False) -> jax.Array:
-    """q: (B, H, Sq, D); k, v: (B, Hkv, Skv, D).  GQA groups are expanded by
-    the wrapper in ``ops.py``; here H == Hkv."""
+    """q: (B, H, Sq, D); k: (B, Hkv, Skv, D); v: (B, Hkv, Skv, Dv), where
+    V's head dim may differ from Q's and K's (MLA: 192 and 128).  GQA
+    groups are expanded by the wrapper in ``ops.py``; here H == Hkv."""
     B, H, Sq, D = q.shape
     _, _, Skv, _ = k.shape
+    Dv = v.shape[-1]
     bq = min(block_q, Sq)
     bkv = min(block_kv, Skv)
     assert Sq % bq == 0 and Skv % bkv == 0, (Sq, bq, Skv, bkv)
 
     qf = q.reshape(B * H, Sq, D)
     kf = k.reshape(B * H, Skv, D)
-    vf = v.reshape(B * H, Skv, D)
+    vf = v.reshape(B * H, Skv, Dv)
     grid = (B * H, Sq // bq, Skv // bkv)
 
     out = pl.pallas_call(
@@ -85,15 +87,15 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
         in_specs=[
             pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, bkv, D), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, bkv, D), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((1, bkv, Dv), lambda b, i, j: (b, j, 0)),
         ],
-        out_specs=pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((B * H, Sq, D), q.dtype),
+        out_specs=pl.BlockSpec((1, bq, Dv), lambda b, i, j: (b, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((B * H, Sq, Dv), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
-            pltpu.VMEM((bq, D), jnp.float32),
+            pltpu.VMEM((bq, Dv), jnp.float32),
         ],
         interpret=interpret,
     )(qf, kf, vf)
-    return out.reshape(B, H, Sq, D)
+    return out.reshape(B, H, Sq, Dv)

@@ -16,6 +16,7 @@ import jax.numpy as jnp
 from repro.kernels.chunk_scan import chunk_scan_pallas
 from repro.kernels.decode_attention import decode_attention_pallas
 from repro.kernels.flash_attention import flash_attention_pallas
+from repro.kernels.grouped_matmul import grouped_matmul_pallas
 from repro.kernels.matmul import matmul_pallas
 
 
@@ -38,6 +39,18 @@ def matmul(x: jax.Array, w: jax.Array,
     bm, bn, bk = tiles if tiles is not None else _default_matmul_tiles(M, N, K)
     return matmul_pallas(x, w, block_m=bm, block_n=bn, block_k=bk,
                          interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("tiles", "interpret"))
+def grouped_matmul(x: jax.Array, w: jax.Array, group_sizes: jax.Array, *,
+                   tiles: Tuple[int, int, int],
+                   interpret: bool = False) -> jax.Array:
+    """x (M, K) sorted by group, w (G, K, N), group_sizes (G,) -> (M, N)
+    on tiles (bm, bn, bk); the caller gives the baseline where the plan
+    has none (``costmodel.baseline_tiles`` of the site)."""
+    bm, bn, bk = tiles[:3]
+    return grouped_matmul_pallas(x, w, group_sizes, block_m=bm, block_n=bn,
+                                 block_k=bk, interpret=interpret)
 
 
 @functools.partial(jax.jit,

@@ -66,18 +66,47 @@ def rope_freqs(dim: int, theta: float):
     return 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
 
 
+YARN_BETA_FAST, YARN_BETA_SLOW = 32.0, 1.0    # YaRN's (and DeepSeek-V2's)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def yarn_freqs(dim: int, theta: float, factor: float, original_ctx: int,
+               beta_fast: float = YARN_BETA_FAST,
+               beta_slow: float = YARN_BETA_SLOW):
+    """YaRN inverse frequencies (DeepSeek-V2's modelling code): pair i of
+    ``dim`` keeps its frequency below the ramp, is divided by ``factor``
+    above it, and blends linearly over [floor(c(beta_fast)),
+    ceil(c(beta_slow))], where c(r) = dim ln(original_ctx / (2 pi r)) /
+    (2 ln theta) is the pair that turns r times over ``original_ctx``."""
+    def corr(r):
+        return dim * math.log(original_ctx / (r * 2 * math.pi)) / (
+            2 * math.log(theta))
+
+    lo = max(math.floor(corr(beta_fast)), 0)
+    hi = min(math.ceil(corr(beta_slow)), dim - 1)
+    extra = rope_freqs(dim, theta)
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - lo)
+                    / max(hi - lo, 1e-3), 0.0, 1.0)
+    return extra / factor * ramp + extra * (1.0 - ramp)
+
+
 def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
-               mode: str = "1d") -> jax.Array:
+               mode: str = "1d", freqs=None) -> jax.Array:
     """x: (B, H, S, D); positions: (S,) or (B, S) absolute positions.
 
     mode "1d": rotate all D dims (pairing [0::2], [1::2]).
     mode "2d": GLM-style — rotate only the first half of D, pass the rest.
+    ``freqs``: the pairs' inverse frequencies, if not ``theta``'s own.
     """
     if mode == "none":
         return x
     B, H, S, D = x.shape
     rot_dim = D // 2 if mode == "2d" else D
-    freqs = rope_freqs(rot_dim, theta)                       # (rot_dim/2,)
+    if freqs is None:
+        freqs = rope_freqs(rot_dim, theta)                   # (rot_dim/2,)
     if positions.ndim == 1:
         ang = positions[:, None].astype(jnp.float32) * freqs[None, :]
         ang = ang[None, None]                                # (1,1,S,rd/2)

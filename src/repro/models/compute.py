@@ -5,7 +5,8 @@ interleave_count(IF)`` above each loop.  Here, every tunable hot op in the
 model zoo goes through :func:`matmul` / :func:`flash_attention` /
 :func:`decode_attention` with a *site* label.  Three modes:
 
-* ``xla``     — plain jnp ops (the default; what the dry-run lowers).
+* ``xla``     — plain jnp ops (the default; what the dry-run lowers);
+  grouped matmuls run ``jax.lax.ragged_dot``.
 * ``pallas``  — route through the Pallas kernels in ``repro.kernels`` using
   tile factors from the active :class:`TileProgram` (the "pragma" — see
   ``repro.core.vectorizer``).  Missing sites fall back to the heuristic
@@ -106,11 +107,14 @@ class KernelSite:
     """A tunable kernel instance — the analogue of one extracted loop."""
 
     site: str            # stable label, e.g. "attn.qkv_proj"
-    kind: str            # "matmul" | "attention" | "chunk_scan"
-    m: int               # rows (tokens) — matmul M / attention q_len
+    kind: str            # "matmul" | "grouped_matmul" | "attention" |
+                         # "chunk_scan"
+    m: int               # rows (tokens) — matmul M / attention q_len;
+                         # grouped: the rows all groups expect together
     n: int               # cols — matmul N / attention head_dim
     k: int               # contraction — matmul K / attention kv_len
-    batch: int = 1       # leading batch (attention B*heads; matmul 1)
+    batch: int = 1       # leading batch (attention B*heads; matmul 1;
+                         # grouped matmul: the number of groups)
     dtype: str = "bfloat16"
     transpose: str = "nn"    # operand layouts
     causal: bool = False
@@ -167,6 +171,38 @@ def matmul(x: jax.Array, w: jax.Array, *, site: str,
                             interpret=st.interpret)
             return y.reshape(*lead, N)
         return jnp.matmul(x, w)
+
+
+def grouped_matmul(x: jax.Array, w: jax.Array, group_sizes: jax.Array, *,
+                   site: str, rows: Optional[int] = None) -> jax.Array:
+    """Ragged product: ``x`` (M, K) holds the rows of group 0, then of
+    group 1, ..., then rows of no group; group g's rows times ``w[g]``
+    (K, N).  ``group_sizes`` (G,) int32 may hold zeros.  Returns (M, N),
+    rows past ``sum(group_sizes)`` zero.
+
+    Recorded as a ``grouped_matmul`` site (m = ``rows``, the rows the
+    groups expect together, M where not given; n, k; batch = G).  In
+    ``pallas`` mode the grouped-matmul kernel runs it on the plan's
+    (bm, bn, bk); in ``xla`` mode ``jax.lax.ragged_dot``, which has a
+    gradient."""
+    M, K = x.shape
+    G, K2, N = w.shape
+    assert K == K2, (site, x.shape, w.shape)
+    st = _STATE
+    ksite = KernelSite(site=site, kind="grouped_matmul",
+                       m=int(M if rows is None else rows), n=int(N),
+                       k=int(K), batch=int(G), dtype=str(x.dtype))
+    if st.recorder is not None:
+        st.recorder.record(ksite)
+    with jax.named_scope(f"site={site}"):
+        if st.mode == "pallas":
+            from repro.core.costmodel import baseline_tiles
+            from repro.kernels import ops as kops
+            tiles = None if st.tiles is None else st.tiles.get(ksite.key())
+            return kops.grouped_matmul(
+                x, w, group_sizes, interpret=st.interpret,
+                tiles=tuple(tiles or baseline_tiles(ksite)))
+        return jax.lax.ragged_dot(x, w, group_sizes.astype(jnp.int32))
 
 
 def einsum(spec: str, *args, site: str) -> jax.Array:

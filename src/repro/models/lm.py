@@ -10,8 +10,12 @@
 
 The layer stack is a single ``lax.scan`` over ``cfg.n_periods`` with each
 period's parameters stacked on the leading axis (small HLO, fast compiles,
-remat via ``jax.checkpoint`` around the period body).  Encoder-decoder
-configs scan two stacks and add cross-attention.
+remat via ``jax.checkpoint`` around the period body).  Leading dense layers
+(``cfg.n_dense_layers``, DeepSeek-V2's ``first_k_dense_replace``) are a
+stack and a scan of their own before it, with their own parameters
+(``params["dense_blocks"]``) and cache (``cache["dense"]``); a config with
+none has neither.  Encoder-decoder configs scan two stacks and add
+cross-attention.
 """
 from __future__ import annotations
 
@@ -41,12 +45,14 @@ class Model:
 # parameter init
 # ---------------------------------------------------------------------------
 
-def _stack_init(cfg: ModelConfig, key, dtype, n_units: int, cross: bool = False):
+def _stack_init(cfg: ModelConfig, key, dtype, n_units: int,
+                cross: bool = False, period: Optional[tuple] = None):
     """Stacked per-period params: tuple over period slots, leaves
     (n_periods, ...)."""
-    n_periods = n_units // len(cfg.period)
+    period = period or cfg.period
+    n_periods = n_units // len(period)
     out = []
-    for slot, b in enumerate(cfg.period):
+    for slot, b in enumerate(period):
         keys = jax.random.split(jax.random.fold_in(key, slot), n_periods)
         per = [blocks.block_init(cfg, b, k, dtype) for k in keys]
         if cross:
@@ -77,7 +83,12 @@ def model_init(cfg: ModelConfig, key):
                                       cross=True)
         p["enc_norm"] = norm_init(cfg, cfg.d_model, dtype)
     else:
-        p["blocks"] = _stack_init(cfg, ks[3], dtype, cfg.n_layers)
+        p["blocks"] = _stack_init(cfg, ks[3], dtype,
+                                  cfg.n_layers - cfg.n_dense_layers)
+        if cfg.n_dense_layers:
+            p["dense_blocks"] = _stack_init(cfg, ks[5], dtype,
+                                            cfg.n_dense_layers,
+                                            period=(cfg.dense_block,))
     return p
 
 
@@ -87,8 +98,10 @@ def model_init(cfg: ModelConfig, key):
 
 def _run_stack(cfg: ModelConfig, stack_params, x, *, positions, causal,
                caches=None, decode_pos=None, memory=None, mem_caches=None,
-               mem_init=None, remat: bool = True):
-    """Scan the period stack.  Returns (x, new_caches, new_mem, aux_sums).
+               mem_init=None, remat: bool = True,
+               period: Optional[tuple] = None):
+    """Scan the period stack (``period``: its slots, ``cfg.period`` by
+    default).  Returns (x, new_caches, new_mem, aux_sums).
 
     Caches travel in the scan CARRY and are updated in place with
     dynamic_update_index (XLA aliases while-loop carry buffers), never as
@@ -109,6 +122,7 @@ def _run_stack(cfg: ModelConfig, stack_params, x, *, positions, causal,
     decode_attn = has_cache and decode_pos is not None and not cfg.mla
 
     from jax.sharding import PartitionSpec as _P
+    period = period or cfg.period
 
     def _slice(tree, i):
         return jax.tree.map(
@@ -132,7 +146,7 @@ def _run_stack(cfg: ModelConfig, stack_params, x, *, positions, causal,
                    "router_z": jnp.zeros((), jnp.float32)}
         new_caches = list(caches_c) if has_cache else None
         new_mem = list(mem_c) if mem_c is not None else None
-        for slot, b in enumerate(cfg.period):
+        for slot, b in enumerate(period):
             pp = slot_params[slot]
             stack = decode_attn and b.kind == "attn"
             cs = None
@@ -223,12 +237,22 @@ def decoder_forward(cfg, params, batch, caches=None, decode_pos=None):
         x = _embed(cfg, params, batch["tokens"])
         positions = decode_pos + jnp.arange(x.shape[1])
         mask, n_pre = None, 0
+    remat = decode_pos is None and caches is None
+    dense_caches = None
+    if cfg.n_dense_layers:
+        x, dense_caches, _, _ = _run_stack(
+            cfg, params["dense_blocks"], x, positions=positions, causal=True,
+            caches=None if caches is None else caches["dense"],
+            decode_pos=decode_pos, remat=remat, period=(cfg.dense_block,))
     x, new_caches, _, aux = _run_stack(
         cfg, params["blocks"], x, positions=positions, causal=True,
-        caches=caches, decode_pos=decode_pos,
-        remat=(decode_pos is None and caches is None))
+        caches=None if caches is None else caches["caches"],
+        decode_pos=decode_pos, remat=remat)
     x = apply_norm(cfg, params["final_norm"], x)
-    return x, new_caches, aux, mask, n_pre
+    out_caches = None if caches is None else {"caches": new_caches}
+    if dense_caches is not None:
+        out_caches["dense"] = dense_caches
+    return x, out_caches, aux, mask, n_pre
 
 
 def encdec_forward(cfg, params, batch, caches=None, decode_pos=None,
@@ -297,18 +321,21 @@ def train_loss(cfg: ModelConfig, params, batch):
 
 
 def make_cache(cfg: ModelConfig, batch: int, ctx: int, dtype):
-    n_periods = ((cfg.n_dec_layers if cfg.enc_dec else cfg.n_layers)
-                 // len(cfg.period))
+    n_periods = (cfg.n_dec_layers // len(cfg.period) if cfg.enc_dec
+                 else cfg.n_periods)
 
-    def stacked(mk):
+    def stacked(mk, n=n_periods):
         one = mk()
         return jax.tree.map(
-            lambda a: jnp.broadcast_to(a, (n_periods,) + a.shape).copy(), one)
+            lambda a: jnp.broadcast_to(a, (n,) + a.shape).copy(), one)
 
     caches = tuple(
         stacked(lambda b=b: blocks.block_cache(cfg, b, batch, ctx, dtype))
         for b in cfg.period)
     out = {"caches": caches}
+    if cfg.n_dense_layers:
+        out["dense"] = (stacked(lambda: blocks.block_cache(
+            cfg, cfg.dense_block, batch, ctx, dtype), cfg.n_dense_layers),)
     if cfg.enc_dec:
         out["mem"] = tuple(
             stacked(lambda: attention.make_cross_cache(cfg, batch, ctx, dtype))
@@ -324,9 +351,8 @@ def prefill(cfg: ModelConfig, params, batch, cache):
             mem_init=cache["mem"])
         out_cache = {"caches": new_caches, "mem": new_mem}
     else:
-        x, new_caches, _, _, _ = decoder_forward(
-            cfg, params, batch, caches=cache["caches"])
-        out_cache = {"caches": new_caches}
+        x, out_cache, _, _, _ = decoder_forward(cfg, params, batch,
+                                                caches=cache)
     logits = _logits(cfg, params, x[:, -1:])[:, 0]
     return logits, out_cache
 
@@ -341,9 +367,8 @@ def decode_step(cfg: ModelConfig, params, token, pos, cache):
             mem_caches=cache["mem"], decode_pos=pos)
         out_cache = {"caches": new_caches, "mem": cache["mem"]}
     else:
-        x, new_caches, _, _, _ = decoder_forward(
-            cfg, params, batch, caches=cache["caches"], decode_pos=pos)
-        out_cache = {"caches": new_caches}
+        x, out_cache, _, _, _ = decoder_forward(cfg, params, batch,
+                                                caches=cache, decode_pos=pos)
     logits = _logits(cfg, params, x)[:, 0]
     return logits, out_cache
 

@@ -1,35 +1,45 @@
-"""Token-choice top-k MoE with GShard-style capacity dispatch.
+"""Dropless token-choice top-k MoE over the experts held here.
 
-Dispatch/combine are INDEX-based gathers wrapped in custom VJPs whose
-backward passes are *also* gathers (via the inverse index maps) — three
-reasons, all measured on the 256-chip dry-run:
+The router has the full ``n_experts`` width and runs in float32.  A token's
+experts are its top ``moe_top_k`` softmax scores; with ``n_group`` > 1
+(DeepSeek-V2's group-limited greedy) only experts of the ``topk_group``
+groups with the highest best score compete.  Gates are the softmax
+probabilities, renormalised over the chosen experts where
+``norm_topk_prob``, else times ``routed_scaling_factor``.
 
- 1. one-hot dispatch einsums would dominate cost_analysis by ~1000x and
-    poison the roofline (DESIGN.md §5);
- 2. a (K*T, d) gathered-rows intermediate replicates (30 GiB/device);
- 3. the *transpose* of a gather is a scatter, and GSPMD's scatter
-    partitioning falls back to replicating the (T, d) operand (16+ GiB) —
-    expressing each backward as the dual gather keeps every heavy tensor
-    sharded in both passes.
+Expert parallelism: this layer holds routed experts ``expert_offset`` ..
+``expert_offset + held_experts - 1`` (all of them unless the config says
+otherwise) and computes their part of the result for every route that
+lands on them.  Routes to experts held elsewhere add nothing here; there
+is no exchange and nothing stands in for it.  Held routes are sorted by
+expert and run as ragged groups (``compute.grouped_matmul``, sites
+``moe.experts.gate`` / ``.up`` / ``.down``), so no route is ever dropped
+and a group may be empty.  Shared experts add their share
+(``shared_width``, a tensor-parallel slice where the config cuts it).
+Long inputs run in chunks of at most ``ROUTE_CHUNK`` routes, which bounds
+the sorted buffers and the float32 sums.
 
-Expert tensors are stacked (E, d, f), sharded E over "model" (expert
-parallelism) + d over "data" (FSDP); dispatch buffers shard (E, C) over
-(TP, DP), so the token->expert movement is GSPMD's all-to-all.
+Each expert's weights are drawn from its own key (``fold_in`` of the
+expert's global index), so a layer that holds a slice of the experts holds
+exactly those experts of the whole layer.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as _P
 
 from repro.configs.base import ModelConfig
 from repro.models import compute
 from repro.models.common import dense_init, split_keys
 
-CAPACITY_FACTOR = 1.25
+ROUTE_CHUNK = 32768     # routes (tokens x top-k) sorted at once
 
-_ECD = lambda dp, tp: _P(tp, dp, None)
-_TD = lambda dp, tp: _P(dp, None)
+
+def _experts_init(key, cfg: ModelConfig, shape, dtype):
+    """(held, *shape): held expert e is drawn from fold_in(key, e)."""
+    ids = cfg.expert_offset + jnp.arange(cfg.held_experts)
+    return jax.vmap(lambda e: dense_init(jax.random.fold_in(key, e), shape,
+                                         dtype))(ids)
 
 
 def moe_init(cfg: ModelConfig, key, dtype):
@@ -37,108 +47,71 @@ def moe_init(cfg: ModelConfig, key, dtype):
     ks = split_keys(key, 7)
     p = {
         "router": dense_init(ks[0], (d, e), jnp.float32),
-        "ewi": dense_init(ks[1], (e, d, f), dtype),
-        "ewg": dense_init(ks[2], (e, d, f), dtype),
-        "ewo": dense_init(ks[3], (e, f, d), dtype),
+        "ewi": _experts_init(ks[1], cfg, (d, f), dtype),
+        "ewg": _experts_init(ks[2], cfg, (d, f), dtype),
+        "ewo": _experts_init(ks[3], cfg, (f, d), dtype),
     }
     if cfg.n_shared_experts:
-        fs = f * cfg.n_shared_experts
+        fs = cfg.shared_width
         p["shared_wi"] = dense_init(ks[4], (d, fs), dtype)
         p["shared_wg"] = dense_init(ks[5], (d, fs), dtype)
         p["shared_wo"] = dense_init(ks[6], (fs, d), dtype)
     return p
 
 
-def _capacity(n_tokens: int, n_experts: int, top_k: int) -> int:
-    c = int(n_tokens * top_k * CAPACITY_FACTOR / n_experts)
-    return max(8, -(-c // 8) * 8)   # multiple of 8, >= 8
+def route(cfg: ModelConfig, probs):
+    """(T, E) router probabilities -> (gate (T, K), expert ids (T, K))."""
+    T, E = probs.shape
+    if cfg.n_group > 1:
+        grouped = probs.reshape(T, cfg.n_group, E // cfg.n_group)
+        _, top_groups = jax.lax.top_k(grouped.max(-1), cfg.topk_group)
+        keep = jnp.zeros((T, cfg.n_group), bool).at[
+            jnp.arange(T)[:, None], top_groups].set(True)
+        probs = jnp.where(jnp.repeat(keep, E // cfg.n_group, axis=1),
+                          probs, 0.0)
+    gate, eidx = jax.lax.top_k(probs, cfg.moe_top_k)
+    if cfg.norm_topk_prob:
+        gate = gate / jnp.maximum(gate.sum(-1, keepdims=True), 1e-9)
+    else:
+        gate = gate * cfg.routed_scaling_factor
+    return gate, eidx
 
 
-# ---------------------------------------------------------------------------
-# dispatch: xt (T,d) -> buf (E,C,d); backward is K gathers, not a scatter
-# ---------------------------------------------------------------------------
-
-@jax.custom_vjp
-def _dispatch(xt, idx, eidx, pos_tk, keep_tk):
-    return _dispatch_fwd(xt, idx, eidx, pos_tk, keep_tk)[0]
-
-
-def _dispatch_fwd(xt, idx, eidx, pos_tk, keep_tk):
-    T = xt.shape[0]
-    valid = idx >= 0
-    buf = jnp.where(valid[..., None], xt[jnp.clip(idx, 0, T - 1)], 0)
-    buf = compute.constrain(buf, _ECD)
-    return buf, (idx.shape[1], eidx, pos_tk, keep_tk, T)
-
-
-def _dispatch_bwd(res, dbuf):
-    C, eidx, pos_tk, keep_tk, T = res
-    K = eidx.shape[1]
-    d = dbuf.shape[-1]
-    # single-axis (flat) gathers only: GSPMD partitions those; the 2-index
-    # form replicates the operand
-    dbuf_flat = compute.constrain(dbuf.reshape(-1, d), _TD)
-    d_xt = 0.0
-    for k in range(K):
-        flat = eidx[:, k] * C + jnp.clip(pos_tk[:, k], 0, C - 1)
-        rows = compute.constrain(dbuf_flat[flat], _TD)
-        d_xt = d_xt + jnp.where(keep_tk[:, k:k + 1], rows, 0)
-    return compute.constrain(d_xt, _TD), None, None, None, None
+def _held_experts(cfg: ModelConfig, p, xt, gate, eidx):
+    """The held experts' part of the result for tokens ``xt`` (T, d) routed
+    to ``eidx`` (T, K) with ``gate``: (T, d) float32."""
+    T, d = xt.shape
+    K, H = cfg.moe_top_k, cfg.held_experts
+    local = eidx.reshape(-1) - cfg.expert_offset                # (T*K,)
+    held = (local >= 0) & (local < H)
+    order = jnp.argsort(jnp.where(held, local, H), stable=True)
+    group_sizes = jnp.zeros((H,), jnp.int32).at[
+        jnp.where(held, local, H)].add(1, mode="drop")
+    tok = order // K
+    x_sorted = xt[tok]                                          # (T*K, d)
+    rows = -(-T * K * H // cfg.n_experts)      # the rows the groups expect
+    gmm = lambda a, w, site: compute.grouped_matmul(
+        a, w, group_sizes, site=site, rows=rows)
+    h = (jax.nn.silu(gmm(x_sorted, p["ewg"], "moe.experts.gate"))
+         * gmm(x_sorted, p["ewi"], "moe.experts.up"))
+    y_sorted = gmm(h, p["ewo"], "moe.experts.down")
+    w = jnp.where(held[order], gate.reshape(-1)[order], 0.0)
+    return jnp.zeros((T, d), jnp.float32).at[tok].add(
+        y_sorted.astype(jnp.float32) * w[:, None])
 
 
-_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+def _moe_tokens(cfg: ModelConfig, p, xt, gate, eidx):
+    """The layer's output for tokens ``xt`` (T, d): the held experts' part
+    plus the shared experts', in ``xt``'s dtype."""
+    y = _held_experts(cfg, p, xt, gate, eidx)
+    if cfg.n_shared_experts:
+        hs = (jax.nn.silu(compute.matmul(xt, p["shared_wg"],
+                                         site="moe.shared_gate", fused_ops=1))
+              * compute.matmul(xt, p["shared_wi"], site="moe.shared_up"))
+        y = y + compute.matmul(hs, p["shared_wo"],
+                               site="moe.shared_down").astype(jnp.float32)
+    return y.astype(xt.dtype)
 
-
-# ---------------------------------------------------------------------------
-# combine: y_buf (E,C,d), w (T,K) -> y (T,d); backward gathers via idx/kidx
-# ---------------------------------------------------------------------------
-
-@jax.custom_vjp
-def _combine(y_buf, w, idx, kidx, eidx, pos_tk):
-    return _combine_fwd(y_buf, w, idx, kidx, eidx, pos_tk)[0]
-
-
-def _combine_fwd(y_buf, w, idx, kidx, eidx, pos_tk):
-    E, C, d = y_buf.shape
-    K = w.shape[1]
-    y_flat = compute.constrain(y_buf.reshape(-1, d), _TD)
-    y = 0.0
-    for k in range(K):
-        flat = eidx[:, k] * C + jnp.clip(pos_tk[:, k], 0, C - 1)
-        y_k = compute.constrain(y_flat[flat], _TD)
-        y = y + y_k.astype(jnp.float32) * w[:, k:k + 1]
-    y = compute.constrain(y, _TD)
-    return y, (y_buf, w, idx, kidx, eidx, pos_tk)
-
-
-def _combine_bwd(res, dy):
-    y_buf, w, idx, kidx, eidx, pos_tk = res
-    E, C, d = y_buf.shape
-    T, K = w.shape
-    dy = compute.constrain(dy, _TD)
-    valid = idx >= 0
-    # d_y_buf[e,c] = w[idx[e,c], kidx[e,c]] * dy[idx[e,c]] — flat gathers
-    w_flat = w.T.reshape(-1)                                # slot-major (K*T,)
-    w_ec = jnp.where(valid, w_flat[jnp.clip(kidx, 0, K - 1) * T
-                                   + jnp.clip(idx, 0, T - 1)], 0.0)
-    d_y_buf = jnp.where(valid[..., None],
-                        dy[jnp.clip(idx, 0, T - 1)], 0) * w_ec[..., None]
-    d_y_buf = compute.constrain(d_y_buf.astype(y_buf.dtype), _ECD)
-    # d_w[t,k] = dy[t] . y_buf[e_k(t), pos_k(t)]            — flat gathers
-    y_flat = compute.constrain(y_buf.reshape(-1, d), _TD)
-    dws = []
-    for k in range(K):
-        flat = eidx[:, k] * C + jnp.clip(pos_tk[:, k], 0, C - 1)
-        y_k = compute.constrain(y_flat[flat], _TD)
-        dws.append((dy * y_k.astype(jnp.float32)).sum(-1))
-    d_w = jnp.stack(dws, axis=1)
-    return d_y_buf, d_w, None, None, None, None
-
-
-_combine.defvjp(_combine_fwd, _combine_bwd)
-
-
-# ---------------------------------------------------------------------------
 
 def apply_moe(cfg: ModelConfig, p, x):
     """x: (B, S, d) -> (y, aux) where aux = {"lb_loss", "router_z"}."""
@@ -150,8 +123,7 @@ def apply_moe(cfg: ModelConfig, p, x):
     logits = compute.matmul(xt.astype(jnp.float32), p["router"],
                             site="moe.router")                  # (T,E) f32
     probs = jax.nn.softmax(logits, axis=-1)
-    gate, eidx = jax.lax.top_k(probs, K)                        # (T,K)
-    gate = gate / jnp.maximum(gate.sum(-1, keepdims=True), 1e-9)
+    gate, eidx = route(cfg, probs)
 
     # ---- aux losses (Switch-style load balance + router z-loss) ----
     me = probs.mean(0)                                          # (E,)
@@ -160,48 +132,16 @@ def apply_moe(cfg: ModelConfig, p, x):
     lb_loss = E * jnp.sum(me * ce)
     router_z = jnp.mean(jax.nn.logsumexp(logits, axis=-1) ** 2)
 
-    # ---- capacity assignment (slot-major priority, as GShard) ----
-    C = _capacity(T, E, K)
-    a_e = eidx.T.reshape(-1)                                    # (K*T,) slot-major
-    onehot = jax.nn.one_hot(a_e, E, dtype=jnp.int32)            # (KT,E)
-    # expert dim over TP: the cumsum is per-column, so it partitions cleanly
-    onehot = compute.constrain(onehot, lambda dp, tp: _P(None, tp))
-    pos = (jnp.cumsum(onehot, axis=0) * onehot).sum(-1) - 1     # (KT,)
-    keep = pos < C
-    tok = jnp.tile(jnp.arange(T), K)                            # (KT,)
-    slot = jnp.repeat(jnp.arange(K), T)                         # (KT,)
-
-    # (E, C) inverse maps: token id and slot id per expert slot.  These two
-    # scatters are the only scatters in the layer and are int32 (E, C) —
-    # a few MiB, safe to let GSPMD replicate.
-    pc = jnp.where(keep, pos, C)
-    idx = jnp.full((E, C), -1, jnp.int32).at[a_e, pc].set(
-        tok.astype(jnp.int32), mode="drop")
-    kidx = jnp.full((E, C), 0, jnp.int32).at[a_e, pc].set(
-        slot.astype(jnp.int32), mode="drop")
-    idx = compute.constrain(idx, lambda dp, tp: _P(tp, dp))
-    kidx = compute.constrain(kidx, lambda dp, tp: _P(tp, dp))
-
-    pos_tk = pos.reshape(K, T).T                                # (T,K)
-    keep_tk = keep.reshape(K, T).T
-    w = gate * keep_tk.astype(jnp.float32)                      # (T,K)
-
-    # ---- dispatch / expert compute / combine ----
-    xt_c = compute.constrain(xt, _TD)
-    buf = _dispatch(xt_c, idx, eidx, pos_tk, keep_tk)           # (E,C,d)
-    h = compute.constrain(jnp.einsum("ecd,edf->ecf", buf, p["ewi"]), _ECD)
-    g = jax.nn.silu(
-        compute.constrain(jnp.einsum("ecd,edf->ecf", buf, p["ewg"]), _ECD))
-    y_buf = compute.constrain(
-        jnp.einsum("ecf,efd->ecd", h * g, p["ewo"]), _ECD)       # (E,C,d)
-    y = _combine(y_buf, w, idx, kidx, eidx, pos_tk)             # (T,d) f32
-
-    if cfg.n_shared_experts:
-        hs = (jax.nn.silu(compute.matmul(xt, p["shared_wg"],
-                                         site="moe.shared_gate", fused_ops=1))
-              * compute.matmul(xt, p["shared_wi"], site="moe.shared_up"))
-        y = y + compute.matmul(hs, p["shared_wo"],
-                               site="moe.shared_down").astype(jnp.float32)
+    # ---- experts, dropless, in chunks of at most ROUTE_CHUNK routes ----
+    n_chunks = next(n for n in range(1, T + 1)
+                    if T % n == 0 and (T // n) * K <= ROUTE_CHUNK
+                    or n == T)
+    if n_chunks == 1:
+        y = _moe_tokens(cfg, p, xt, gate, eidx)
+    else:
+        split = lambda a: a.reshape(n_chunks, T // n_chunks, *a.shape[1:])
+        y = jax.lax.map(lambda c: _moe_tokens(cfg, p, *c),
+                        (split(xt), split(gate), split(eidx)))
 
     aux = {"lb_loss": lb_loss, "router_z": router_z}
-    return y.astype(x.dtype).reshape(B, S, d), aux
+    return y.reshape(B, S, d), aux

@@ -153,6 +153,11 @@ def _pack_sites(sites: Sequence[KernelSite], pad_to: int):
             "k": np.array(k, np.int32), "batch": np.array(b, np.int32),
             "causal": np.array(causal, bool), "s": np.array(sb, np.int32),
             "peak": np.array(peak, np.float32)}
+    unknown = sorted({s.kind for s in sites} - set(KINDS))
+    if unknown:
+        # the fused grid holds no pricing for these: refuse, do not guess
+        raise ValueError(f"FusedTuner cannot price site kinds {unknown}; "
+                         f"it prices {KINDS}")
     kind_idx = np.array([_KIND_IDX[s.kind] for s in sites]
                         + [0] * (pad_to - len(sites)), np.int32)
     return cols, kind_idx

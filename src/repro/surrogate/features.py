@@ -74,9 +74,10 @@ def _vmem_and_grid(sites: Sequence[KernelSite],
             # tiles (chunk, 1, 1); P=site.n, N=site.k
             vmem[idx] = 2 * a * (nn + 2 * kk) * s + nn * kk * 4 + a * a * 4
             grid[idx] = np.ceil(b * m / a)
-        else:                               # unknown kind: neutral values
-            vmem[idx] = s
-            grid[idx] = 1.0
+        else:
+            # no scratch formula for this kind: refuse, do not misprice
+            raise ValueError(f"no surrogate features for site kind "
+                             f"{kind!r}; featurized kinds: {KINDS}")
     return vmem, np.maximum(grid, 1.0)
 
 

@@ -77,6 +77,49 @@ def test_cost_vec_property_parity(m, n, k, dt, kind, b):
             assert abs(grid[j] - c) <= 1e-9 * c
 
 
+@pytest.mark.parametrize("m,n,k,g", [
+    (96, 1536, 5120, 20), (96, 5120, 1536, 20), (6144, 1536, 5120, 20),
+    (3, 640, 300, 7), (1000, 128, 4096, 1), (40, 64, 64, 4)])
+def test_grouped_matmul_cost_vec_parity(m, n, k, g):
+    """The grouped kind on the matmul action row: scalar and vector prices
+    agree on every action (each group's rows padded to bm, weights read
+    once per row tile, bn and bk fitted to divisors of N and K)."""
+    site = KernelSite(site="moe.experts.up", kind="grouped_matmul", m=m,
+                      n=n, k=k, batch=g)
+    assert SPACE.choices("grouped_matmul") == SPACE.choices("matmul")
+    grid = costmodel_vec.cost_grid_kind(SPACE, [site], "grouped_matmul")[0]
+    tiles = costmodel_vec.action_tiles_grid(SPACE, "grouped_matmul")
+    for j, t in enumerate(tiles):
+        c = costmodel.site_cost(site, tuple(int(x) for x in t))
+        if c is None:
+            assert np.isinf(grid[j]), t
+        else:
+            assert abs(grid[j] - c) <= 1e-9 * c, (t, c, grid[j])
+    fit = costmodel_vec.fit_tile_vec(np.array([n, k])[:, None],
+                                     tiles[None, :, 1])
+    assert [[costmodel.fit_tile(d, int(t)) for t in tiles[:, 1]]
+            for d in (n, k)] == fit.tolist()
+    base = costmodel_vec.baseline_costs([site])[0]
+    assert abs(base - costmodel.baseline_cost(site)) <= 1e-9 * base
+    assert tuple(costmodel_vec.baseline_tiles_batch([site])[0]) == \
+        costmodel.baseline_tiles(site)
+
+
+def test_grouped_matmul_cost_pads_each_group_to_bm():
+    """20 groups of about 5 rows: a 512-row tile pays 100x the padded
+    compute of an 8-row tile, and the weights are read once either way."""
+    site = KernelSite(site="g", kind="grouped_matmul", m=96, n=1536,
+                      k=5120, batch=20)
+    assert costmodel.fit_tile(5120, 4096) == 2560
+    assert costmodel.fit_tile(1536, 1024) == 768
+    assert costmodel.fit_tile(300, 128) == 300
+    small = costmodel.site_cost(site, (8, 512, 4096))
+    big = costmodel.site_cost(site, (512, 512, 4096))
+    weights = 20 * 5120 * 1536 * 2 / costmodel.HBM_BW
+    assert weights < small < 2 * weights
+    assert big > 2 * small
+
+
 def test_cost_vec_no_int64_overflow_at_huge_dims():
     # byte/grid products exceed int64 for dims ~2^22+; the engine must
     # promote to float64 and keep parity with the arbitrary-precision

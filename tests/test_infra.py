@@ -327,6 +327,30 @@ def test_cache_specs_shard_ctx_where_each_cache_keeps_it(
             assert spec == P(*want), (part, name, spec)
 
 
+def test_leading_dense_layers_have_their_own_params_and_cache_specs():
+    """DeepSeek-V2's leading dense layer is a stack of its own: its
+    parameters and its latent cache get the same rules as the period
+    stack's (expert tensors over "model", the latent's rank over "model")."""
+    from jax.sharding import PartitionSpec as P
+
+    class FakeMesh:
+        axis_names = ("data", "model")
+        shape = {"data": 2, "model": 2}
+
+    cfg = get_config("deepseek_v2_236b").reduced()
+    model = build_model(cfg)
+    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    specs = shd.param_specs(shapes)
+    assert specs["dense_blocks"][0]["mlp"]["wi"] == P(None, "data", "model")
+    assert specs["blocks"][0]["mlp"]["ewi"] == P(None, "model", "data", None)
+    cache = jax.eval_shape(lambda: model.make_cache(4, 32, jnp.bfloat16))
+    assert set(cache) == {"caches", "dense"}
+    cspecs = shd.cache_specs(cfg, ShapeConfig("t", 32, 4, "decode"),
+                             FakeMesh(), cache)
+    for part in ("caches", "dense"):
+        assert cspecs[part][0]["c_kv"] == P(None, ("data",), None, "model")
+
+
 def test_fit_spec_drops_indivisible_axes():
     from jax.sharding import PartitionSpec as P
     mesh = jax.make_mesh((1,), ("model",))

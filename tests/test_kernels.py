@@ -20,6 +20,7 @@ except ImportError:                    # ... deterministic sweep on bare envs
 
 from repro.configs.neurovec import DEFAULT as NV
 from repro.kernels import ops, ref
+from repro.kernels.grouped_matmul import grouped_matmul_pallas
 from repro.kernels.matmul import _ceil_mult
 
 
@@ -76,6 +77,49 @@ def test_matmul_property(m, n, k, bm, bn, bk):
 
 
 # ---------------------------------------------------------------------------
+# grouped (ragged) matmul
+# ---------------------------------------------------------------------------
+
+GMM_CASES = [
+    # rows, K, N, group sizes (empty groups, rows left over past the groups)
+    (37, 256, 384, (7, 0, 13, 1, 9)),
+    (64, 128, 256, (0, 0, 64, 0)),
+    (50, 384, 128, (5, 5, 5, 5, 5, 5, 5, 5, 5, 5)),
+    (19, 64, 80, (0, 3, 16)),
+    (16, 128, 128, (0, 0, 0)),                  # no row routed here
+]
+
+
+@pytest.mark.parametrize("tiles", [(8, 128, 128), (16, 256, 512),
+                                   (128, 128, 256)])
+@pytest.mark.parametrize("case", GMM_CASES)
+def test_grouped_matmul_matches_plain_product(case, tiles):
+    """Each group's rows times its own weights, on ragged groups with empty
+    ones and row counts that ``bm`` does not divide; rows past the groups
+    come out zero."""
+    M, K, N, sizes = case
+    key = jax.random.PRNGKey(len(sizes))
+    x = jax.random.normal(key, (M, K), jnp.float32)
+    w = jax.random.normal(jax.random.fold_in(key, 1), (len(sizes), K, N))
+    gs = jnp.asarray(sizes, jnp.int32)
+    bm, bn, bk = tiles
+    got = jax.jit(lambda x, w, gs: grouped_matmul_pallas(
+        x, w, gs, block_m=bm, block_n=bn, block_k=bk, interpret=True))(
+            x, w, gs)
+    want = np.zeros((M, N), np.float32)
+    start = 0
+    for g, n in enumerate(sizes):
+        want[start:start + n] = np.asarray(x[start:start + n]) @ np.asarray(
+            w[g])
+        start += n
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-4,
+                               atol=1e-4 * np.abs(want).max())
+    ragged = jax.lax.ragged_dot(x, w, gs)
+    np.testing.assert_allclose(np.asarray(ragged), want, rtol=1e-4,
+                               atol=1e-4 * np.abs(want).max())
+
+
+# ---------------------------------------------------------------------------
 # flash attention
 # ---------------------------------------------------------------------------
 
@@ -92,6 +136,22 @@ def test_flash_attention(causal, hq, hkv, tiles):
     rep = hq // hkv
     yr = ref.attention_ref(q, jnp.repeat(k, rep, 1), jnp.repeat(v, rep, 1),
                            causal=causal, scale=0.125)
+    assert float(jnp.max(jnp.abs(y - yr))) < 2e-5
+
+
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("dqk,dv", [(192, 128), (48, 32)])
+def test_flash_attention_v_head_dim_apart(causal, dqk, dv):
+    """MLA's expanded prefill: queries and keys at 192 (nope + rope), values
+    and outputs at 128."""
+    key = jax.random.PRNGKey(3)
+    q = jax.random.normal(key, (2, 4, 256, dqk))
+    k = jax.random.normal(jax.random.fold_in(key, 1), (2, 4, 256, dqk))
+    v = jax.random.normal(jax.random.fold_in(key, 2), (2, 4, 256, dv))
+    y = ops.flash_attention(q, k, v, causal=causal, scale=dqk ** -0.5,
+                            tiles=(64, 128), interpret=True)
+    yr = ref.attention_ref(q, k, v, causal=causal, scale=dqk ** -0.5)
+    assert y.shape == (2, 4, 256, dv)
     assert float(jnp.max(jnp.abs(y - yr))) < 2e-5
 
 

@@ -67,6 +67,14 @@ class TestFusedTuner:
         for s, a in zip(SITES, ref):
             assert prog.tiles[s.key()] == space.tiles(s.kind, a)
 
+    def test_refuses_a_kind_it_cannot_price(self):
+        """A grouped matmul has no formula in the fused grid: the tuner
+        refuses it rather than pricing it as another kind."""
+        gmm = KernelSite(site="moe.experts.up", kind="grouped_matmul",
+                         m=96, n=1536, k=5120, batch=20)
+        with pytest.raises(ValueError, match="grouped_matmul"):
+            FusedTuner(CFG).actions(SITES[:1] + [gmm])
+
     def test_one_dispatch_and_bucketed_trace_reuse(self):
         """tune() is ONE device dispatch; batch sizes inside one
         power-of-two bucket reuse the jit specialization (no retrace)."""

@@ -126,3 +126,41 @@ def test_cached_executable_keeps_its_own_site_scopes(tmp_path, monkeypatch):
         for k, v in before.items():
             jax.config.update(k, v)
         cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def deepseek_hlo():
+    """DeepSeek-V2 at a small size: a leading dense layer, then MLA with
+    group-routed held experts (2 of 4) and a shared expert."""
+    cfg = get_config("deepseek_v2_236b").reduced(n_held_experts=2,
+                                                 expert_offset=1)
+    model = build_model(cfg)
+    params = serve.init_params(model, 0)
+    cache = jax.eval_shape(lambda: model.make_cache(B, 2 * S, jnp.float32))
+    batch = {"tokens": jax.ShapeDtypeStruct((B, S), jnp.int32)}
+    sites = serve.serving_sites(model, params, batch, cache)
+    base = api.baseline_program(sites)
+    hlo = {(phase, mode): _compiled(model, params, cache, phase,
+                                    base if mode == "pallas" else None)
+           for phase in ("prefill", "decode") for mode in ("xla", "pallas")}
+    return sites, hlo
+
+
+@pytest.mark.parametrize("mode", ["xla", "pallas"])
+@pytest.mark.parametrize("phase", ["prefill", "decode"])
+def test_moe_and_mla_sites_name_their_ops(deepseek_hlo, phase, mode):
+    """The held experts' grouped products, the router, the shared experts,
+    the MLA projections and the attention core (the absorbed decode core
+    included) each name their ops, and the three grouped products are the
+    extracted ``grouped_matmul`` sites."""
+    sites, hlo = deepseek_hlo
+    text = hlo[phase, mode]
+    scoped = {m for n in OP_NAME.findall(text) for m in SITE.findall(n)}
+    want = {"moe.experts.gate", "moe.experts.up", "moe.experts.down",
+            "moe.router", "moe.shared_gate", "moe.shared_up",
+            "moe.shared_down", "mla.q_down", "mla.q_up", "mla.kv_down",
+            "mla.o", "mla.core", "mlp.gate", "mlp.up", "mlp.down", "lm_head"}
+    assert want <= scoped, sorted(want - scoped)
+    assert {s.site for s in sites if s.kind == "grouped_matmul"} == {
+        "moe.experts.gate", "moe.experts.up", "moe.experts.down"}
+    assert [n for n in OP_NAME.findall(text) if n.count("site=") > 1] == []

@@ -66,6 +66,13 @@ def test_featurizer_shape_finite_deterministic():
         tiles[:1])[0])
 
 
+def test_featurizer_refuses_a_kind_it_has_no_formula_for():
+    gmm = KernelSite(site="moe.experts.up", kind="grouped_matmul", m=96,
+                     n=1536, k=5120, batch=20)
+    with pytest.raises(ValueError, match="grouped_matmul"):
+        featurize([MM, gmm], np.array([[16, 128, 128], [8, 512, 512]]))
+
+
 def test_featurizer_illegal_tile_still_finite():
     # the analytic-prior feature is clamped for illegal tiles; the row
     # must stay finite so training never sees inf/nan
