@@ -26,7 +26,8 @@ def step_ops(run: dict, phase: str, batch: int, prompt_len: int,
     r, rq = run["kv_lora_rank"], run["q_lora_rank"]
     dn, dr, dv = run["qk_nope_dim"], run["qk_rope_dim"], run["v_head_dim"]
     E, held, K = run["n_experts"], run["n_held_experts"], run["moe_top_k"]
-    f, fe, fs = run["d_ff"], run["moe_d_ff"], run["shared_d_ff"]
+    f, fe = run["d_ff"], run["moe_d_ff"]
+    fs = run["n_shared_experts"] * fe
     M = batch * prompt_len if phase == "prefill" else batch
 
     def mm(name, m, n, k, mult):

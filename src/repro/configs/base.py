@@ -66,7 +66,6 @@ class ModelConfig:
     n_dense_layers: int = 0        # leading dense layers before the period
     expert_offset: int = 0         # first routed expert held here
     n_held_experts: int = 0        # routed experts held here (0 = all)
-    shared_d_ff: int = 0           # shared experts' width (0 = n_shared * moe_d_ff)
     n_group: int = 1               # routing groups (group-limited greedy)
     topk_group: int = 1            # groups a token may route into
     routed_scaling_factor: float = 1.0
@@ -142,10 +141,6 @@ class ModelConfig:
         return self.n_held_experts or self.n_experts
 
     @property
-    def shared_width(self) -> int:
-        return self.shared_d_ff or self.n_shared_experts * self.moe_d_ff
-
-    @property
     def d_head_total(self) -> int:
         return self.n_heads * self.head_dim
 
@@ -197,7 +192,6 @@ class ModelConfig:
             moe_d_ff=64 if self.moe_d_ff else 0,
             expert_offset=0,
             n_held_experts=0,
-            shared_d_ff=0,
             n_group=min(self.n_group, 2),
             topk_group=1,
             kv_lora_rank=32 if self.mla else 0,
@@ -251,8 +245,7 @@ def _count_params(c: ModelConfig, active_only: bool = False) -> int:
     def moe_mlp_params(active: bool) -> int:
         mult = 3 if _gated(c.act) else 2
         n_routed = c.moe_top_k if active else c.held_experts
-        p = n_routed * mult * d * c.moe_d_ff
-        p += mult * d * c.shared_width
+        p = (n_routed + c.n_shared_experts) * mult * d * c.moe_d_ff
         p += d * c.n_experts                                      # router
         return p
 

@@ -13,7 +13,6 @@ from __future__ import annotations
 import math
 from typing import Optional, Tuple
 
-from repro.kernels.grouped_matmul import fit_tile   # the kernel's bn, bk
 from repro.models.compute import KernelSite
 
 # ---- TPU v5e hardware constants (also used by the roofline analysis) ----
@@ -104,6 +103,21 @@ def baseline_matmul_tiles(M: int, N: int, K: int) -> Tuple[int, int, int]:
 # ===========================================================================
 # grouped matmul (ragged groups: the held experts of an MoE layer)
 # ===========================================================================
+
+def fit_tile(dim: int, t: int) -> int:
+    """The grouped-matmul kernel's ``bn`` / ``bk`` for a tile of ``t``: the
+    largest multiple of 128 that is at most ``t`` and divides ``dim``; the
+    whole ``dim`` where it is at most ``t`` or no multiple of 128 divides
+    it."""
+    if dim <= t:
+        return dim
+    b = t // LANE * LANE
+    while b >= LANE:
+        if dim % b == 0:
+            return b
+        b -= LANE
+    return dim
+
 
 def grouped_matmul_cost(site: KernelSite,
                         tiles: Tuple[int, int, int]) -> Optional[float]:

@@ -21,21 +21,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-LANE = 128
-
-
-def fit_tile(dim: int, t: int) -> int:
-    """The largest multiple of 128 that is at most ``t`` and divides
-    ``dim``; the whole ``dim`` where it is at most ``t`` or no multiple of
-    128 divides it."""
-    if dim <= t:
-        return dim
-    b = t // LANE * LANE
-    while b >= LANE:
-        if dim % b == 0:
-            return b
-        b -= LANE
-    return dim
+from repro.core.costmodel import fit_tile
 
 
 def _gmm_kernel(tile_group_ref, x_ref, w_ref, o_ref, acc_ref, *, n_k: int):

@@ -14,8 +14,8 @@ lands on them.  Routes to experts held elsewhere add nothing here; there
 is no exchange and nothing stands in for it.  Held routes are sorted by
 expert and run as ragged groups (``compute.grouped_matmul``, sites
 ``moe.experts.gate`` / ``.up`` / ``.down``), so no route is ever dropped
-and a group may be empty.  Shared experts add their share
-(``shared_width``, a tensor-parallel slice where the config cuts it).
+and a group may be empty.  The shared experts, whole on every chip that
+holds routed experts, add their part for every token.
 Long inputs run in chunks of at most ``ROUTE_CHUNK`` routes, which bounds
 the sorted buffers and the float32 sums.
 
@@ -52,7 +52,7 @@ def moe_init(cfg: ModelConfig, key, dtype):
         "ewo": _experts_init(ks[3], cfg, (f, d), dtype),
     }
     if cfg.n_shared_experts:
-        fs = cfg.shared_width
+        fs = cfg.n_shared_experts * cfg.moe_d_ff
         p["shared_wi"] = dense_init(ks[4], (d, fs), dtype)
         p["shared_wg"] = dense_init(ks[5], (d, fs), dtype)
         p["shared_wo"] = dense_init(ks[6], (fs, d), dtype)
@@ -136,12 +136,9 @@ def apply_moe(cfg: ModelConfig, p, x):
     n_chunks = next(n for n in range(1, T + 1)
                     if T % n == 0 and (T // n) * K <= ROUTE_CHUNK
                     or n == T)
-    if n_chunks == 1:
-        y = _moe_tokens(cfg, p, xt, gate, eidx)
-    else:
-        split = lambda a: a.reshape(n_chunks, T // n_chunks, *a.shape[1:])
-        y = jax.lax.map(lambda c: _moe_tokens(cfg, p, *c),
-                        (split(xt), split(gate), split(eidx)))
+    split = lambda a: a.reshape(n_chunks, T // n_chunks, *a.shape[1:])
+    y = jax.lax.map(lambda c: _moe_tokens(cfg, p, *c),
+                    (split(xt), split(gate), split(eidx)))
 
     aux = {"lb_loss": lb_loss, "router_z": router_z}
     return y.reshape(B, S, d), aux

@@ -253,7 +253,7 @@ def _deepseek_small(**kw):
     """DeepSeek-V2's routing and latent attention at a small width: 32
     routed experts in 8 groups, 3 groups a token, top 6, gates times 16."""
     base = dict(n_experts=32, n_group=8, topk_group=3, moe_top_k=6,
-                moe_d_ff=32, n_shared_experts=2, shared_d_ff=0)
+                moe_d_ff=32, n_shared_experts=2)
     base.update(kw)
     return get_config("deepseek_v2_236b").reduced(**base)
 
