@@ -11,10 +11,15 @@ unrouted rows cost no grid steps.  A group's weights are read once per row
 tile it fills (once where its rows fit one tile).  ``(bm, bn, bk)`` are the
 NeuroVectorizer-tunable factors; ``bn`` and ``bk`` shrink to a multiple of
 128 that divides N and K (``fit_tile``), so no block runs past the weights.
+
+The weights are ``(G, K, N)``, or a stack ``(L, G, K, N)`` read at a
+scalar-prefetched layer index where it lies: the layer scan's stacked
+experts reach the kernel whole, with no copy of the layer.
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -24,8 +29,9 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core.costmodel import fit_tile
 
 
-def _gmm_kernel(tile_group_ref, x_ref, w_ref, o_ref, acc_ref, *, n_k: int):
-    del tile_group_ref
+def _gmm_kernel(tile_group_ref, layer_ref, x_ref, w_ref, o_ref, acc_ref, *,
+                n_k: int):
+    del tile_group_ref, layer_ref
     k = pl.program_id(2)
 
     @pl.when(k == 0)
@@ -42,11 +48,16 @@ def _gmm_kernel(tile_group_ref, x_ref, w_ref, o_ref, acc_ref, *, n_k: int):
 
 def grouped_matmul_pallas(x: jax.Array, w: jax.Array, group_sizes: jax.Array,
                           *, block_m: int, block_n: int, block_k: int,
+                          layer: Optional[jax.Array] = None,
                           interpret: bool = False) -> jax.Array:
     """x (M, K) sorted by group, w (G, K, N), group_sizes (G,) int32 ->
-    (M, N); rows past ``sum(group_sizes)`` are zero."""
+    (M, N); rows past ``sum(group_sizes)`` are zero.  Or w (L, G, K, N)
+    and ``layer`` an int32 scalar: the groups' weights are ``w[layer]``,
+    read in place."""
     M, K = x.shape
-    G, K2, N = w.shape
+    if w.ndim == 3:
+        w, layer = w[None], 0
+    _, G, K2, N = w.shape
     assert K == K2, (x.shape, w.shape)
     bm = min(block_m, -(-M // 8) * 8)
     bn, bk = fit_tile(N, block_n), fit_tile(K, block_k)
@@ -70,14 +81,15 @@ def grouped_matmul_pallas(x: jax.Array, w: jax.Array, group_sizes: jax.Array,
     out = pl.pallas_call(
         functools.partial(_gmm_kernel, n_k=n_k),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=2,
             grid=(N // bn, n_active, n_k),
             in_specs=[
-                pl.BlockSpec((bm, bk), lambda n, t, k, tg: (t, k)),
-                pl.BlockSpec((None, bk, bn),
-                             lambda n, t, k, tg: (tg[t], k, n)),
+                pl.BlockSpec((bm, bk), lambda n, t, k, tg, at: (t, k)),
+                pl.BlockSpec((None, None, bk, bn),
+                             lambda n, t, k, tg, at: (at[0], tg[t], k, n)),
             ],
-            out_specs=pl.BlockSpec((bm, bn), lambda n, t, k, tg: (t, n)),
+            out_specs=pl.BlockSpec((bm, bn),
+                                   lambda n, t, k, tg, at: (t, n)),
             scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((n_tiles * bm, N), x.dtype),
@@ -85,6 +97,6 @@ def grouped_matmul_pallas(x: jax.Array, w: jax.Array, group_sizes: jax.Array,
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
         name="grouped_matmul",
-    )(tile_group, xp, w)
+    )(tile_group, jnp.asarray(layer, jnp.int32).reshape(1), xp, w)
     y = out[jnp.minimum(dest, n_tiles * bm - 1)]
     return jnp.where((g < G)[:, None], y, jnp.zeros_like(y))

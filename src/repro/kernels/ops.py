@@ -33,24 +33,28 @@ def _default_attn_tiles(Sq: int, Skv: int) -> Tuple[int, int]:
 @functools.partial(jax.jit, static_argnames=("tiles", "interpret"))
 def matmul(x: jax.Array, w: jax.Array,
            tiles: Optional[Tuple[int, int, int]] = None,
-           interpret: bool = False) -> jax.Array:
+           interpret: bool = False, layer=None) -> jax.Array:
+    """x (M, K) times w (K, N) -> (M, N); or w (L, K, N) and ``layer`` an
+    int32 scalar: times ``w[layer]``, read where it lies in the stack."""
     M, K = x.shape
-    _, N = w.shape
+    N = w.shape[-1]
     bm, bn, bk = tiles if tiles is not None else _default_matmul_tiles(M, N, K)
     return matmul_pallas(x, w, block_m=bm, block_n=bn, block_k=bk,
-                         interpret=interpret)
+                         layer=layer, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("tiles", "interpret"))
 def grouped_matmul(x: jax.Array, w: jax.Array, group_sizes: jax.Array, *,
-                   tiles: Tuple[int, int, int],
-                   interpret: bool = False) -> jax.Array:
+                   tiles: Tuple[int, int, int], interpret: bool = False,
+                   layer=None) -> jax.Array:
     """x (M, K) sorted by group, w (G, K, N), group_sizes (G,) -> (M, N)
-    on tiles (bm, bn, bk); the caller gives the baseline where the plan
-    has none (``costmodel.baseline_tiles`` of the site)."""
+    on tiles (bm, bn, bk); or w (L, G, K, N) and ``layer`` an int32
+    scalar: the groups' weights are ``w[layer]``, read in place.  The
+    caller gives the baseline where the plan has none
+    (``costmodel.baseline_tiles`` of the site)."""
     bm, bn, bk = tiles[:3]
     return grouped_matmul_pallas(x, w, group_sizes, block_m=bm, block_n=bn,
-                                 block_k=bk, interpret=interpret)
+                                 block_k=bk, layer=layer, interpret=interpret)
 
 
 @functools.partial(jax.jit,

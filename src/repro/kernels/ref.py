@@ -10,6 +10,19 @@ def matmul_ref(x: jax.Array, w: jax.Array) -> jax.Array:
                    w.astype(jnp.float32)).astype(x.dtype)
 
 
+def grouped_matmul_ref(x: jax.Array, w: jax.Array,
+                       group_sizes) -> jax.Array:
+    """x (M, K) sorted by group, w (G, K, N): group g's rows times w[g];
+    rows past the groups are zero."""
+    y = jnp.zeros((x.shape[0], w.shape[-1]), jnp.float32)
+    start = 0
+    for g, n in enumerate(group_sizes):
+        y = y.at[start:start + n].set(matmul_ref(
+            x[start:start + n].astype(jnp.float32), w[g]))
+        start += n
+    return y.astype(x.dtype)
+
+
 def attention_ref(q: jax.Array, k: jax.Array, v: jax.Array, *, causal: bool,
                   scale: float) -> jax.Array:
     """q: (B,H,Sq,D); k,v: (B,H,Skv,D) (heads already expanded)."""

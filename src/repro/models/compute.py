@@ -15,10 +15,16 @@ model zoo goes through :func:`matmul` / :func:`flash_attention` /
   (``jax.eval_shape``) registers every site with its concrete shapes/dtypes.
   This is the paper's *loop extractor* (DESIGN.md §2).
 
+In ``pallas`` mode a matmul or grouped-matmul weight that is one layer of
+the layer scan's stacked parameters (:func:`layer_params`) reaches its
+kernel as the whole stack and the scan index, and the kernel reads the
+layer where it lies: no copy of it is made.  Each such operand is counted,
+at trace time, by how it arrived (:func:`weight_reads`).
+
 Every call runs its ops, in every mode, under ``jax.named_scope("site=<site>")``:
-the operand pads, the GQA repeat, the decode cache's column write, the
-``pallas_call`` and its output slice in ``pallas`` mode, the jnp ops in
-``xla`` mode.  XLA keeps the scope in each
+the GQA repeat, the decode cache's column write, the grouped rows' scatter
+and gather, the ``pallas_call`` in ``pallas`` mode, the jnp ops in ``xla``
+mode.  XLA keeps the scope in each
 instruction's ``op_name`` metadata, so the device ops an operator sees in a
 profile (TensorBoard, Perfetto) name their site, and the benchmark reads
 per-site device time from the same scope.  It is trace-time metadata and
@@ -27,10 +33,12 @@ the site fused into a consumer outside it is billed to the consumer.
 """
 from __future__ import annotations
 
+import collections
 import contextlib
 import functools
 import math
-from dataclasses import dataclass, field
+import sys
+from dataclasses import dataclass
 from typing import Optional
 
 import jax
@@ -144,13 +152,94 @@ class SiteRecorder:
 
 
 # ---------------------------------------------------------------------------
+# weights read where they lie in the stacked parameters
+# ---------------------------------------------------------------------------
+
+#: id(slice) -> (slice, stack, index) for each slice ``layer_params`` made
+_LAYERS: dict = {}
+#: the open ``weight_reads`` tallies
+_TALLIES: list = []
+
+
+def current_mode() -> str:
+    return _STATE.mode
+
+
+@contextlib.contextmanager
+def layer_params(stack, index):
+    """Layer ``index`` of each leaf of the scanned parameters ``stack``
+    (leaves (L, ...)), sliced.  While the context is open, a Pallas matmul
+    or grouped matmul given one of these slices as its weight gets the
+    whole stack and ``index`` instead, and reads the layer where it lies;
+    the slice is then left unused and XLA drops it.  Every other use
+    (norms, biases, einsums) reads the slice."""
+    leaves, tree = jax.tree.flatten(stack)
+    sliced = [jax.lax.dynamic_index_in_dim(a, index, 0, keepdims=False)
+              for a in leaves]
+    for s, a in zip(sliced, leaves):
+        _LAYERS[id(s)] = (s, a, index)
+    try:
+        yield jax.tree.unflatten(tree, sliced)
+    finally:
+        for s in sliced:
+            _LAYERS.pop(id(s), None)
+
+
+def _pallas_weight(w: jax.Array, kind: str, site: str):
+    """The weight operand of a Pallas kernel and the layer it reads: the
+    whole stack and the index where ``w`` is a slice ``layer_params``
+    made (``in_place``), else ``w`` itself and None (``materialized``: an
+    array of its own, such as ``lm_head``'s transposed embedding).
+    Counted in the ``compute_pallas_weights_total`` counter and the open
+    ``weight_reads`` tallies."""
+    from repro.obs.metrics import get_registry
+    hit = _LAYERS.get(id(w))
+    in_place = hit is not None and hit[0] is w
+    how = "in_place" if in_place else "materialized"
+    get_registry().counter(
+        "compute_pallas_weights_total",
+        "Pallas kernel weight operands traced, by how each arrived: "
+        "in_place (the whole stack and the layer index) or materialized",
+        labelnames=("kind", "site", "how")).labels(
+            kind=kind, site=site, how=how).inc()
+    for tally in _TALLIES:
+        tally[kind, site, how] += 1
+    return hit[1:] if in_place else (w, None)
+
+
+@contextlib.contextmanager
+def weight_reads(step: str):
+    """Tally, while ``step`` traces, how each Pallas kernel got its weight,
+    and print the tally to stderr once the trace ends: one line for each
+    traced, so each compiled, step.  Yields the tally, a Counter of
+    (kind, site, how) with ``how`` ``in_place`` or ``materialized``."""
+    tally = collections.Counter()
+    _TALLIES.append(tally)
+    try:
+        yield tally
+    finally:
+        _TALLIES.remove(tally)
+    if tally:
+        parts = []
+        for how in ("in_place", "materialized"):
+            sites = sorted({s for _, s, h in tally if h == how})
+            n = sum(c for (_, _, h), c in tally.items() if h == how)
+            parts.append(f"{how} {n}" + (f" ({' '.join(sites)})"
+                                         if sites else ""))
+        print(f"[serve] {step} step, Pallas weight operands: "
+              f"{', '.join(parts)}", file=sys.stderr, flush=True)
+
+
+# ---------------------------------------------------------------------------
 # matmul wrapper
 # ---------------------------------------------------------------------------
 
 
 def matmul(x: jax.Array, w: jax.Array, *, site: str,
            fused_ops: int = 0) -> jax.Array:
-    """``x @ w`` where x is (..., K) and w is (K, N)."""
+    """``x @ w`` where x is (..., K) and w is (K, N).  In ``pallas`` mode a
+    ``w`` that :func:`layer_params` sliced reaches the kernel as the whole
+    stack and the layer index."""
     *lead, K = x.shape
     K2, N = w.shape
     assert K == K2, (site, x.shape, w.shape)
@@ -167,8 +256,9 @@ def matmul(x: jax.Array, w: jax.Array, *, site: str,
                                k=int(K), dtype=str(x.dtype),
                                fused_ops=fused_ops)
             tiles = None if st.tiles is None else st.tiles.get(ksite.key())
+            w, layer = _pallas_weight(w, "matmul", site)
             y = kops.matmul(x.reshape(M, K), w, tiles=tiles,
-                            interpret=st.interpret)
+                            interpret=st.interpret, layer=layer)
             return y.reshape(*lead, N)
         return jnp.matmul(x, w)
 
@@ -183,8 +273,9 @@ def grouped_matmul(x: jax.Array, w: jax.Array, group_sizes: jax.Array, *,
     Recorded as a ``grouped_matmul`` site (m = ``rows``, the rows the
     groups expect together, M where not given; n, k; batch = G).  In
     ``pallas`` mode the grouped-matmul kernel runs it on the plan's
-    (bm, bn, bk); in ``xla`` mode ``jax.lax.ragged_dot``, which has a
-    gradient."""
+    (bm, bn, bk), and a ``w`` that :func:`layer_params` sliced reaches it
+    as the whole stack (L, G, K, N) and the layer index; in ``xla`` mode
+    ``jax.lax.ragged_dot``, which has a gradient."""
     M, K = x.shape
     G, K2, N = w.shape
     assert K == K2, (site, x.shape, w.shape)
@@ -199,8 +290,9 @@ def grouped_matmul(x: jax.Array, w: jax.Array, group_sizes: jax.Array, *,
             from repro.core.costmodel import baseline_tiles
             from repro.kernels import ops as kops
             tiles = None if st.tiles is None else st.tiles.get(ksite.key())
+            w, layer = _pallas_weight(w, "grouped_matmul", site)
             return kops.grouped_matmul(
-                x, w, group_sizes, interpret=st.interpret,
+                x, w, group_sizes, interpret=st.interpret, layer=layer,
                 tiles=tuple(tiles or baseline_tiles(ksite)))
         return jax.lax.ragged_dot(x, w, group_sizes.astype(jnp.int32))
 

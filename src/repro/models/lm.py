@@ -19,6 +19,7 @@ cross-attention.
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 from dataclasses import dataclass
 from typing import Any, Callable, Optional
@@ -113,13 +114,22 @@ def _run_stack(cfg: ModelConfig, stack_params, x, *, positions, causal,
     scan index instead of a slice, and returns the stack written: in
     ``pallas`` mode the decode kernel writes and reads the layer where it
     lies.  Sliced out and written back around a Pallas call, each layer's
-    K/V would be relayouted and copied whole every step."""
+    K/V would be relayouted and copied whole every step.
+
+    The same holds for the weights in ``pallas`` mode: the scan runs over
+    the index alone, and the period body gets the stacked parameters and
+    the index (``compute.layer_params``).  A weight that a matmul or
+    grouped-matmul kernel reads reaches it whole with the index, and the
+    kernel reads the layer where it lies; every other leaf is sliced.  In
+    ``xla`` mode (training, gradients) the scan slices the parameters as
+    its ``xs``."""
     has_cache = caches is not None
     has_mem = memory is not None or mem_caches is not None
     # cross-attn k/v is written only on prefill (cache fill); train
     # recomputes it under remat and decode reuses the cache passed in.
     write_mem = has_mem and mem_caches is None and has_cache
     decode_attn = has_cache and decode_pos is not None and not cfg.mla
+    in_place = compute.current_mode() == "pallas"
 
     from jax.sharding import PartitionSpec as _P
     period = period or cfg.period
@@ -135,6 +145,11 @@ def _run_stack(cfg: ModelConfig, stack_params, x, *, positions, causal,
             tree, upd)
 
     def period_body(x, caches_c, mem_c, slot_params, idx):
+        with (compute.layer_params(slot_params, idx) if in_place
+              else contextlib.nullcontext(slot_params)) as slot_params:
+            return _period(x, caches_c, mem_c, slot_params, idx)
+
+    def _period(x, caches_c, mem_c, slot_params, idx):
         # pin the carry: batch over DP, d over TP.  The carry is what scan
         # saves for backward (n_periods, B, S, d) — sharding d cuts the
         # dominant saved-activation term by the TP degree (ZeRO-R style);
@@ -185,15 +200,17 @@ def _run_stack(cfg: ModelConfig, stack_params, x, *, positions, causal,
 
     def scan_body(carry, slot_inputs):
         x, caches_c, mem_c = carry
-        slot_params, idx = slot_inputs
+        slot_params, idx = ((stack_params, slot_inputs) if in_place
+                            else slot_inputs)
         x, caches_c, mem_c, aux = period_body(x, caches_c, mem_c,
                                               slot_params, idx)
         return (x, caches_c, mem_c), aux
 
     n_periods = jax.tree.leaves(stack_params)[0].shape[0]
+    index = jnp.arange(n_periods)
     (x, new_caches, new_mem), auxes = jax.lax.scan(
         scan_body, (x, caches, mem0),
-        (stack_params, jnp.arange(n_periods)))
+        index if in_place else (stack_params, index))
     aux = jax.tree.map(lambda a: a.sum(), auxes)
     return x, new_caches, new_mem, aux
 

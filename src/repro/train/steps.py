@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+from repro.models import compute
 from repro.models.lm import Model
 from repro.optim import adamw
 
@@ -101,16 +102,22 @@ def make_train_step(model: Model, opt_cfg: adamw.AdamWConfig,
 
 
 def make_prefill_step(model: Model):
+    """The prefill step; tracing it in ``pallas`` mode prints how each
+    Pallas kernel got its weight (``compute.weight_reads``)."""
     def prefill_step(params, batch, cache):
-        return model.prefill(params, batch, cache)
+        with compute.weight_reads("prefill"):
+            return model.prefill(params, batch, cache)
     return prefill_step
 
 
 def make_serve_step(model: Model):
-    """One decode step: sample greedy next token for a batch of requests."""
+    """One decode step: sample greedy next token for a batch of requests.
+    Tracing it in ``pallas`` mode prints how each Pallas kernel got its
+    weight (``compute.weight_reads``)."""
 
     def serve_step(params, token, pos, cache):
-        logits, cache = model.decode_step(params, token, pos, cache)
+        with compute.weight_reads("decode"):
+            logits, cache = model.decode_step(params, token, pos, cache)
         next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)[:, None]
         return next_tok, logits, cache
 

@@ -21,7 +21,7 @@ except ImportError:                    # ... deterministic sweep on bare envs
 from repro.configs.neurovec import DEFAULT as NV
 from repro.kernels import ops, ref
 from repro.kernels.grouped_matmul import grouped_matmul_pallas
-from repro.kernels.matmul import _ceil_mult
+from repro.kernels.matmul import block
 
 
 def _rel_err(a, b):
@@ -117,6 +117,43 @@ def test_grouped_matmul_matches_plain_product(case, tiles):
     ragged = jax.lax.ragged_dot(x, w, gs)
     np.testing.assert_allclose(np.asarray(ragged), want, rtol=1e-4,
                                atol=1e-4 * np.abs(want).max())
+
+
+# ---------------------------------------------------------------------------
+# weights read where they lie in a stack
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("layer", [None, 0, 2, 4],
+                         ids=["plain", "first", "middle", "last"])
+@pytest.mark.parametrize("kernel", ["matmul", "grouped_matmul"])
+def test_stacked_weight_is_read_in_place(kernel, layer):
+    """A weight stack (L, ...) and a layer index give the product with that
+    layer; the other layers are NaN, so a read of a neighbour shows.  The
+    tiles divide none of M, N and K (K is 5 x 128 against bk 256 for the
+    matmul), so the edge blocks run past the operands, where interpret mode
+    puts NaN: the last K block is masked, the rows and columns past the
+    output are not written.  ``plain`` is the unstacked weight."""
+    L, M, K, N, sizes = 5, 37, 5 * 128, 200, (7, 0, 13, 9)
+    key = jax.random.PRNGKey(7)
+    x = jax.random.normal(key, (M, K), jnp.float32)
+    shape = (K, N) if kernel == "matmul" else (len(sizes), K, N)
+    w = jax.random.normal(jax.random.fold_in(key, 1), shape, jnp.float32)
+    if kernel == "matmul":
+        want = ref.matmul_ref(x, w)
+        run = lambda w, at: ops.matmul(x, w, tiles=(16, 128, 256),
+                                       interpret=True, layer=at)
+    else:
+        gs = jnp.asarray(sizes, jnp.int32)
+        want = ref.grouped_matmul_ref(x, w, sizes)
+        run = lambda w, at: ops.grouped_matmul(
+            x, w, gs, tiles=(8, 128, 256), interpret=True, layer=at)
+    if layer is not None:
+        w = jnp.full((L,) + shape, jnp.nan).at[layer].set(w)
+        layer = jnp.int32(layer)
+    got = run(w, layer)
+    assert got.shape == (M, N)
+    assert bool(jnp.all(jnp.isfinite(got)))
+    assert _rel_err(got, want) < 1e-5
 
 
 # ---------------------------------------------------------------------------
@@ -247,14 +284,13 @@ def test_chunk_scan_chunk_invariance():
 # kernels' internal clamping (what the measurement runner executes)
 # ---------------------------------------------------------------------------
 
-# non-pow2 test shape: stresses padding under every tile
+# non-pow2 test shape: stresses the edge blocks under every tile
 _MM_SHAPE = (48, 160, 136)
 
 
 def _mm_sweep():
     M, N, K = _MM_SHAPE
-    eff = {(min(bm, _ceil_mult(M, 8)), min(bn, _ceil_mult(N, 128)),
-            min(bk, _ceil_mult(K, 128)))
+    eff = {(block(M, bm), block(N, bn), block(K, bk))
            for bm, bn, bk in itertools.product(
                NV.bm_choices, NV.bn_choices, NV.bk_choices)}
     return sorted(eff)

@@ -91,8 +91,9 @@ def test_pallas_decode_matches_xla(arch, overrides):
 
     def run(mode):
         with compute.compute_mode(mode, interpret=True):
-            prefill = jax.jit(model.prefill)
-            decode = jax.jit(model.decode_step)
+            # fresh functions: jit's trace cache does not key on the mode
+            prefill = jax.jit(lambda *a: model.prefill(*a))
+            decode = jax.jit(lambda *a: model.decode_step(*a))
             logits, cache = prefill(params, {"tokens": toks},
                                     model.make_cache(B, ctx, jnp.float32))
             out = []
@@ -109,6 +110,104 @@ def test_pallas_decode_matches_xla(arch, overrides):
     for w, g in zip(jax.tree.leaves(want_cache), jax.tree.leaves(got_cache)):
         np.testing.assert_allclose(np.asarray(g), np.asarray(w),
                                    rtol=1e-4, atol=1e-4)
+
+
+#: primitives that change only an array's shape: a weight seen through one
+#: is still the weight
+_SHAPE_ONLY = ("reshape", "broadcast_in_dim", "squeeze", "expand_dims")
+
+
+def _kernel_weights(jaxpr, origin=None, scope=""):
+    """(site, source) of the weight operand of every matmul and
+    grouped-matmul ``pallas_call`` in ``jaxpr``: ``source`` is the
+    primitive that made the operand, looking through calls, scans and
+    shape-only ops; ``input`` for an argument of the step, ``scan_xs``
+    for a layer the scan sliced as its ``xs``."""
+    import re
+    origin = dict(origin or {})
+    out = []
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        where = scope + "/" + str(eqn.source_info.name_stack)
+        src = [origin.get(v, "input") if hasattr(v, "count") or
+               not hasattr(v, "val") else "literal" for v in eqn.invars]
+        if name == "pallas_call":
+            if eqn.params.get("name") in ("matmul", "grouped_matmul"):
+                out.append((re.findall(r"site=([\w.]+)", where)[-1],
+                             src[-1]))
+        elif name == "scan":
+            n_in = eqn.params["num_consts"] + eqn.params["num_carry"]
+            inner = eqn.params["jaxpr"].jaxpr
+            out += _kernel_weights(inner, dict(zip(
+                inner.invars, src[:n_in] + ["scan_xs"] * (len(src) - n_in))),
+                where)
+        else:
+            for p in ("jaxpr", "call_jaxpr", "fun_jaxpr"):
+                inner = eqn.params.get(p)
+                if inner is not None:
+                    inner = getattr(inner, "jaxpr", inner)
+                    out += _kernel_weights(
+                        inner, dict(zip(inner.invars, src)), where)
+        for v in eqn.outvars:
+            origin[v] = src[0] if name in _SHAPE_ONLY else name
+    return out
+
+
+@pytest.mark.parametrize("arch,overrides", [
+    ("stablelm_3b", dict(n_layers=2)),
+    ("deepseek_v2_236b", dict(n_layers=3, n_held_experts=2,
+                              expert_offset=1)),
+], ids=["dense_gated", "mla_moe"])
+def test_pallas_kernels_read_stacked_weights_in_place(arch, overrides):
+    """In ``pallas`` mode every matmul and grouped-matmul kernel of the
+    layer stacks (the leading dense layer's included) gets its weight as
+    the stacked parameter itself, never a slice or a pad of it, and the
+    step's tally counts each such site ``in_place`` (``lm_head`` reads the
+    transposed embedding: ``materialized``); the logits are the ``xla``
+    path's."""
+    from repro.models import compute
+    from repro.train.steps import make_prefill_step, make_serve_step
+    cfg = get_config(arch).reduced(**overrides)
+    assert cfg.n_layers - cfg.n_dense_layers >= 2
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    B, P, ctx = 2, 8, 12
+    toks = _smoke_batch(cfg, B, P)["tokens"]
+
+    def run(mode):
+        # fresh step functions: jit's trace cache does not key on the mode
+        prefill, decode = make_prefill_step(model), make_serve_step(model)
+        with compute.compute_mode(mode, interpret=True):
+            cache = model.make_cache(B, ctx, jnp.float32)
+            with compute.weight_reads("prefill") as reads:
+                jaxprs = [jax.make_jaxpr(prefill)(
+                    params, {"tokens": toks}, cache)]
+            logits, cache = jax.jit(prefill)(params, {"tokens": toks}, cache)
+            out = [logits]
+            tok = jnp.argmax(logits, -1).astype(jnp.int32)[:, None]
+            with compute.weight_reads("decode") as dec_reads:
+                jaxprs.append(jax.make_jaxpr(decode)(
+                    params, tok, jnp.int32(P), cache))
+            for pos in range(P, P + 2):
+                tok, logits, cache = jax.jit(decode)(params, tok,
+                                                     jnp.int32(pos), cache)
+                out.append(logits)
+        return out, jaxprs, [reads, dec_reads]
+
+    want, _, _ = run("xla")
+    got, jaxprs, tallies = run("pallas")
+    for w, g in zip(want, got):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=1e-4, atol=1e-4)
+    for jaxpr, tally in zip(jaxprs, tallies):
+        weights = _kernel_weights(jaxpr.jaxpr)
+        stacked = {site for site, _ in weights if site != "lm_head"}
+        assert len(stacked) >= 7, weights
+        assert {s for s, src in weights if src != "input"} == {"lm_head"}
+        assert [src for s, src in weights if s == "lm_head"] == ["transpose"]
+        how = {(site, h) for (_, site, h), n in tally.items() if n}
+        assert how == {(s, "in_place") for s in stacked} | {
+            ("lm_head", "materialized")}, sorted(how)
 
 
 def _dense_moe_ref(cfg, p, x):

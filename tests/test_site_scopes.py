@@ -80,15 +80,17 @@ def test_no_op_is_under_two_sites(hlo, phase, mode):
 
 @pytest.mark.parametrize("phase", ["prefill", "decode"])
 def test_matmul_operand_pad_carries_its_site(model_and_sites, hlo, phase):
-    # d_model 64 is not a multiple of the kernel's 128-wide K tile, so the
-    # Pallas path pads each projection's operands inside its site
+    # d_model 64 is not a multiple of the kernel's 128-wide K tile.  The
+    # kernel takes the whole dimension as its block (and masks its edge
+    # blocks where a tile does not divide one), so the Pallas path pads no
+    # projection's operand: no pad is left under a matmul site
     model = model_and_sites[0]
     assert model.cfg.d_model % 128
     pads = [OP_NAME.search(line).group(1)
             for line in hlo[phase, "pallas"].splitlines()
             if " pad(" in line and OP_NAME.search(line)]
     for label in ("attn.q", "mlp.gate", "mlp.up", "lm_head"):
-        assert any(f"site={label}/" in n for n in pads), (label, pads)
+        assert not any(f"site={label}/" in n for n in pads), (label, pads)
 
 
 def test_cached_executable_keeps_its_own_site_scopes(tmp_path, monkeypatch):

@@ -130,40 +130,61 @@ def _runs_per_step(hlo: str) -> dict:
     return {body: conds[cond] for body, cond in bodies.items()}
 
 
-@pytest.mark.parametrize("arch,B,ctx", [
-    ("stablelm_3b", 16, 1024), ("stablelm_3b", 8, 2064),
-    ("chatglm3_6b", 32, 1536)],
-    ids=["stablelm_b16_ctx1024", "stablelm_b8_ctx2064",
-         "chatglm_b32_ctx1536"])
-def test_decode_step_reads_the_stacked_cache_in_place(one_chip, arch, B,
-                                                      ctx):
-    # full-width decode under api.inject at baseline tiles: StableLM-3B
-    # (MHA, head_dim 80) at the benchmark's decode cell, and at a ctx 128
-    # does not divide (serve's prompt 2048 + 16 generated); ChatGLM3-6B
-    # (GQA group 16, head_dim 128) at batch 32 against 1536 positions
+@pytest.fixture(scope="module")
+def decode_step(one_chip):
+    """(arch, B, ctx) -> (params' shapes, the compiled text of the
+    full-width decode step under api.inject at baseline tiles), each
+    compiled once for the tests that read it."""
     from repro import api
     from repro.configs import get_config
     from repro.launch import serve
     from repro.models.lm import build_model
     from repro.train.steps import make_serve_step
 
-    model = build_model(get_config(arch))
-    L, H, D = model.cfg.n_layers, model.cfg.n_kv_heads, model.cfg.head_dim
-    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
-    cache = jax.eval_shape(lambda: model.make_cache(B, ctx, jnp.bfloat16))
-    sites = serve.serving_sites(
-        model, params, {"tokens": jax.ShapeDtypeStruct((B, 256), jnp.int32)},
-        cache)
-
     def on_chip(tree):
         return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
             a.shape, a.dtype, sharding=one_chip), tree)
 
-    with api.inject(api.baseline_program(sites)):
-        hlo = jax.jit(make_serve_step(model), donate_argnums=(3,)).lower(
-            on_chip(params), on_chip(jax.ShapeDtypeStruct((B, 1), jnp.int32)),
-            on_chip(jax.ShapeDtypeStruct((), jnp.int32)),
-            on_chip(cache)).compile().as_text()
+    done = {}
+
+    def compiled(arch, B, ctx):
+        if (arch, B, ctx) in done:
+            return done[arch, B, ctx]
+        model = build_model(get_config(arch))
+        params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+        cache = jax.eval_shape(lambda: model.make_cache(B, ctx,
+                                                        jnp.bfloat16))
+        sites = serve.serving_sites(
+            model, params,
+            {"tokens": jax.ShapeDtypeStruct((B, 256), jnp.int32)}, cache)
+        with api.inject(api.baseline_program(sites)):
+            hlo = jax.jit(make_serve_step(model), donate_argnums=(3,)).lower(
+                on_chip(params),
+                on_chip(jax.ShapeDtypeStruct((B, 1), jnp.int32)),
+                on_chip(jax.ShapeDtypeStruct((), jnp.int32)),
+                on_chip(cache)).compile().as_text()
+        done[arch, B, ctx] = (model.cfg, params, hlo)
+        return done[arch, B, ctx]
+
+    return compiled
+
+
+# full-width decode: StableLM-3B (MHA, head_dim 80) at the benchmark's
+# decode cell, and at a ctx 128 does not divide (serve's prompt 2048 + 16
+# generated); ChatGLM3-6B (GQA group 16, head_dim 128) at batch 32 against
+# 1536 positions
+DECODE_CASES = pytest.mark.parametrize("arch,B,ctx", [
+    ("stablelm_3b", 16, 1024), ("stablelm_3b", 8, 2064),
+    ("chatglm3_6b", 32, 1536)],
+    ids=["stablelm_b16_ctx1024", "stablelm_b8_ctx2064",
+         "chatglm_b32_ctx1536"])
+
+
+@DECODE_CASES
+def test_decode_step_reads_the_stacked_cache_in_place(decode_step, arch, B,
+                                                      ctx):
+    cfg, _, hlo = decode_step(arch, B, ctx)
+    L, H, D = cfg.n_layers, cfg.n_kv_heads, cfg.head_dim
     instrs = _instructions(hlo)
     dims_of = {name: dims for _, name, dims, _, _, _ in instrs}
 
@@ -197,3 +218,24 @@ def test_decode_step_reads_the_stacked_cache_in_place(one_chip, arch, B,
             if op == "custom-call" and "site=attn.core" in line
             for n in SCOPED_VMEM.findall(line)]
     assert vmem and max(vmem) <= 64 << 20
+
+
+@DECODE_CASES
+def test_decode_step_reads_the_stacked_weights_in_place(decode_step, arch,
+                                                        B, ctx):
+    # no slice, pad or copy of a layer's weights: the matmul kernels read
+    # each layer where it lies in the stacked parameters, and pad nothing
+    # (ChatGLM's K 13696 and StableLM's vocabulary 50304 do not divide the
+    # tiles); the only weight-sized move is the head's transpose
+    _, params, hlo = decode_step(arch, B, ctx)
+    layer = min(math.prod(a.shape[1:]) for a in
+                jax.tree.leaves(params["blocks"]) if a.ndim == 3)
+    head = params["head"].shape
+    moves = ("dynamic-slice", "pad", "copy")
+    big = [(dims, line.strip()[:160])
+           for _, name, dims, op, _, line in _instructions(hlo)
+           if (op in moves or (op == "fusion"
+                               and any(m in name for m in moves)))
+           and math.prod(dims) >= layer]
+    assert [line for dims, line in big
+            if sorted(dims) != sorted(head)] == []
